@@ -31,5 +31,7 @@ the serving path, not a side gallery:
 
 ``ops.py`` exposes jit'd wrappers that default to ``interpret=True`` off-TPU
 (CPU correctness / parity testing); ``ref.py`` holds the pure-jnp oracles
-the tests allclose against.
+the tests allclose against; ``tests/test_chip_compile.py`` compiles every
+kernel for a described TPU v5e at Llama-2-7B widths, and ``chip_smoke.py``
+checks the attention kernels against their oracles on the chip.
 """

@@ -18,6 +18,11 @@ contraction dim.
   kv_pos   (B, S)          int32     (-1 = empty slot)
   q_pos    scalar int32    (current absolute position, causal bound)
   out      (B, K, G, hd)   f32
+
+The scales and positions enter the kernel as (B, K, 1, S) and (B, 1, S):
+the TPU compiler only accepts blocks whose last two dimensions are
+(8, 128)-divisible or span the array, which a (1, BS) slice of a (K, S) or
+(B, S) plane is not.
 """
 
 from __future__ import annotations
@@ -66,13 +71,14 @@ def _kernel(ns: int, scale: float, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
-    k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]  # (BS, hd)
-    v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+    # scales arrive as (1, BS) rows; .T makes the per-token column
+    k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].T  # (BS, hd)
+    v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].T
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (G, BS)
 
-    kv_pos = pos_ref[0]  # (BS,)
+    kv_pos = pos_ref[0]  # (1, BS)
     valid = (kv_pos >= 0) & (kv_pos <= qpos_ref[0, 0])
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -116,10 +122,10 @@ def decode_attention(q, k_codes, k_scale, v_codes, v_scale, kv_pos, q_pos,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda i, j, si: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda i, j, si: (i, j, si, 0)),
-            pl.BlockSpec((1, 1, bs), lambda i, j, si: (i, j, si)),
+            pl.BlockSpec((1, 1, 1, bs), lambda i, j, si: (i, j, 0, si)),
             pl.BlockSpec((1, 1, bs, hd), lambda i, j, si: (i, j, si, 0)),
-            pl.BlockSpec((1, 1, bs), lambda i, j, si: (i, j, si)),
-            pl.BlockSpec((1, bs), lambda i, j, si: (i, si)),
+            pl.BlockSpec((1, 1, 1, bs), lambda i, j, si: (i, j, 0, si)),
+            pl.BlockSpec((1, 1, bs), lambda i, j, si: (i, 0, si)),
             pl.BlockSpec((1, 1), lambda i, j, si: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda i, j, si: (i, j, 0, 0)),
@@ -130,4 +136,5 @@ def decode_attention(q, k_codes, k_scale, v_codes, v_scale, kv_pos, q_pos,
             pltpu.VMEM((g, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k_codes, k_scale, v_codes, v_scale, kv_pos, qpos_arr)
+    )(q, k_codes, k_scale[:, :, None], v_codes, v_scale[:, :, None],
+      kv_pos[:, None], qpos_arr)

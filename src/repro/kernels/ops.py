@@ -1,8 +1,10 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies execute in Python for correctness validation) and False on
-real TPU backends.
+``interpret=None`` (the default) follows the default backend: compiled
+Mosaic kernels on a TPU, the Pallas interpreter anywhere else (CPU test
+runs, where the kernel bodies execute as plain JAX against the oracles in
+``kernels.ref``). Pass ``interpret=False`` to lower for a TPU from a
+machine without one, e.g. to compile for a described topology.
 """
 
 from __future__ import annotations

@@ -11,9 +11,14 @@ known before the body runs).
 
 Pool layout (one pool per layer; `serving.kv_pool` owns allocation):
 
-  k_codes  (P, K, page, hd) int8     k_scale (P, K, page) f32
-  v_codes  (P, K, page, hd) int8     v_scale (P, K, page) f32
-  pool_pos (P, page)        int32    (-1 = empty/pad slot)
+  k_codes  (P, K, page, hd) int8     k_scale (P, K, 1, page) f32
+  v_codes  (P, K, page, hd) int8     v_scale (P, K, 1, page) f32
+  pool_pos (P, 1, page)     int32    (-1 = empty/pad slot)
+
+The unit axis before ``page`` on the scale and position leaves keeps every
+block's last two dimensions whole: the TPU compiler only accepts blocks
+whose last two dimensions are (8, 128)-divisible or span the array, and a
+``(1, page)`` slice of a ``(K, page)`` or ``(P, page)`` plane is neither.
 
 Per-request operands:
 
@@ -58,13 +63,14 @@ def _kernel(nb: int, scale: float, bt_ref, qp_ref, q_ref, kc_ref, ks_ref,
 
     r = pl.program_id(0)
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
-    k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]  # (page, hd)
-    v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+    # scales arrive as (1, page) rows; .T makes the per-token column
+    k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].T  # (page, hd)
+    v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].T
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (G, page)
 
-    kv_pos = pos_ref[0]  # (page,)
+    kv_pos = pos_ref[0]  # (1, page)
     valid = (kv_pos >= 0) & (kv_pos <= qp_ref[r])
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -92,7 +98,7 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
     p, _, page, _ = k_codes.shape
     nb = block_table.shape[1]
     assert block_table.shape[0] == r and q_pos.shape == (r,)
-    assert pool_pos.shape == (p, page)
+    assert k_scale.shape == (p, kh, 1, page) and pool_pos.shape == (p, 1, page)
     scale = 1.0 / (hd ** 0.5)
     kern = functools.partial(_kernel, nb, scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -102,13 +108,14 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
             pl.BlockSpec((1, 1, g, hd), lambda i, j, si, bt, qp: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, page, hd),
                          lambda i, j, si, bt, qp: (bt[i, si], j, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda i, j, si, bt, qp: (bt[i, si], j, 0)),
+            pl.BlockSpec((1, 1, 1, page),
+                         lambda i, j, si, bt, qp: (bt[i, si], j, 0, 0)),
             pl.BlockSpec((1, 1, page, hd),
                          lambda i, j, si, bt, qp: (bt[i, si], j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page),
+                         lambda i, j, si, bt, qp: (bt[i, si], j, 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda i, j, si, bt, qp: (bt[i, si], j, 0)),
-            pl.BlockSpec((1, page), lambda i, j, si, bt, qp: (bt[i, si], 0)),
+                         lambda i, j, si, bt, qp: (bt[i, si], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda i, j, si, bt, qp: (i, j, 0, 0)),

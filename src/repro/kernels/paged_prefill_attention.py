@@ -26,13 +26,18 @@ precision) is exactly the dense-gather path's:
 Operand layout (pool exactly as ``serving.kv_pool`` holds it):
 
   q            (R, K, S, G, hd)      queries, kv-head-major
-  k/v_codes    (P, K, page, hd) int8  k/v_scale (P, K, page) f32
-  pool_pos     (P, page) int32        (-1 = empty slot)
+  k/v_codes    (P, K, page, hd) int8  k/v_scale (P, K, 1, page) f32
+  pool_pos     (P, 1, page) int32     (-1 = empty slot)
   block_table  (R, nb) int32          (unused entries → trash page 0)
   q_pos        (R, S) int32           per-token absolute positions (-1 pad)
   start        (R,) int32             first in-call position (2^30 if none)
   k/v_fresh    (R, K, S, hd)          this call's keys/values, full precision
   out          (R, K, S, G, hd) f32
+
+The unit axes keep each block's last two dimensions whole, as the TPU
+compiler requires; for the same reason ``q_pos`` enters the kernel as
+``(R, 1, S)`` twice — the whole row (the fresh keys' positions) and the
+q block's slice (the query rows' positions).
 
 Grid: one program per (request, kv_head, query block); the minor axis walks
 ``nb`` block-table pages then the single fresh block. A fully-masked page
@@ -84,7 +89,7 @@ def _fold(q2, k, v, valid, g, m_ref, l_ref, acc_ref):
 
 
 def _kernel(nb: int, qb: int, scale: float, bt_ref, start_ref, q_ref, qp_ref,
-            kc_ref, ks_ref, vc_ref, vs_ref, pos_ref, fk_ref, fv_ref,
+            qpb_ref, kc_ref, ks_ref, vc_ref, vs_ref, pos_ref, fk_ref, fv_ref,
             o_ref, m_ref, l_ref, acc_ref):
     si = pl.program_id(3)
 
@@ -95,34 +100,31 @@ def _kernel(nb: int, qb: int, scale: float, bt_ref, start_ref, q_ref, qp_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     r = pl.program_id(0)
-    qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (QB, G, hd)
     g, hd = q.shape[1], q.shape[2]
     q2 = q.reshape(qb * g, hd)
-    # per-row query positions of THIS q block (the operand carries the full
-    # row so the fresh step below can mask every in-call key against them)
-    qp = qp_ref[0, pl.ds(qi * qb, qb)]  # (QB,)
+    # per-row query positions of THIS q block as a column (the full row in
+    # qp_ref lets the fresh step below mask every in-call key against them)
+    qp = qpb_ref[0].T  # (QB, 1)
     start = start_ref[r]
 
     @pl.when(si < nb)
     def _pool_page():
-        k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-        kv_pos = pos_ref[0]  # (page,)
+        k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].T  # (page, hd)
+        v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].T
+        kv_pos = pos_ref[0]  # (1, page)
         # history only: stored positions below the row's first in-call
         # position (this call's own tokens live in the pool too — post-
         # update — and are attended as fresh keys instead)
-        valid = ((kv_pos[None, :] >= 0) & (kv_pos[None, :] < start)
-                 & (kv_pos[None, :] <= qp[:, None]))
+        valid = (kv_pos >= 0) & (kv_pos < start) & (kv_pos <= qp)
         _fold(q2, k, v, valid, g, m_ref, l_ref, acc_ref)
 
     @pl.when(si == nb)
     def _fresh_and_finish():
         k = fk_ref[0, 0].astype(jnp.float32)  # (S, hd) full precision
         v = fv_ref[0, 0].astype(jnp.float32)
-        kv_pos = qp_ref[0]  # (S,) — fresh keys sit at the call's positions
-        valid = ((kv_pos[None, :] >= 0)
-                 & (kv_pos[None, :] <= qp[:, None]))  # causal in-call
+        kv_pos = qp_ref[0]  # (1, S) — fresh keys sit at the call's positions
+        valid = (kv_pos >= 0) & (kv_pos <= qp)  # causal in-call
         _fold(q2, k, v, valid, g, m_ref, l_ref, acc_ref)
         # a row whose every key was masked (pad column / inactive row)
         # never raises m above its init — emit exact zeros, not the
@@ -144,7 +146,7 @@ def paged_prefill_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
     p, _, page, _ = k_codes.shape
     nb = block_table.shape[1]
     assert block_table.shape[0] == r and q_pos.shape == (r, s)
-    assert start.shape == (r,) and pool_pos.shape == (p, page)
+    assert start.shape == (r,) and pool_pos.shape == (p, 1, page)
     assert k_fresh.shape == (r, kh, s, hd) and v_fresh.shape == k_fresh.shape
     qb = min(q_block, s)
     pad = (-s) % qb
@@ -168,22 +170,23 @@ def paged_prefill_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
         in_specs=[
             pl.BlockSpec((1, 1, qb, g, hd),
                          lambda i, j, qi, si, bt, st: (i, j, qi, 0, 0)),
-            pl.BlockSpec((1, sp), lambda i, j, qi, si, bt, st: (i, 0)),
+            pl.BlockSpec((1, 1, sp), lambda i, j, qi, si, bt, st: (i, 0, 0)),
+            pl.BlockSpec((1, 1, qb), lambda i, j, qi, si, bt, st: (i, 0, qi)),
             pl.BlockSpec((1, 1, page, hd),
+                         lambda i, j, qi, si, bt, st:
+                         (bt[i, jnp.minimum(si, last)], j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page),
+                         lambda i, j, qi, si, bt, st:
+                         (bt[i, jnp.minimum(si, last)], j, 0, 0)),
+            pl.BlockSpec((1, 1, page, hd),
+                         lambda i, j, qi, si, bt, st:
+                         (bt[i, jnp.minimum(si, last)], j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page),
                          lambda i, j, qi, si, bt, st:
                          (bt[i, jnp.minimum(si, last)], j, 0, 0)),
             pl.BlockSpec((1, 1, page),
                          lambda i, j, qi, si, bt, st:
-                         (bt[i, jnp.minimum(si, last)], j, 0)),
-            pl.BlockSpec((1, 1, page, hd),
-                         lambda i, j, qi, si, bt, st:
-                         (bt[i, jnp.minimum(si, last)], j, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda i, j, qi, si, bt, st:
-                         (bt[i, jnp.minimum(si, last)], j, 0)),
-            pl.BlockSpec((1, page),
-                         lambda i, j, qi, si, bt, st:
-                         (bt[i, jnp.minimum(si, last)], 0)),
+                         (bt[i, jnp.minimum(si, last)], 0, 0)),
             pl.BlockSpec((1, 1, sp, hd),
                          lambda i, j, qi, si, bt, st: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, sp, hd),
@@ -202,6 +205,6 @@ def paged_prefill_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, kh, sp, g, hd), jnp.float32),
         interpret=interpret,
-    )(block_table, start, q, q_pos, k_codes, k_scale, v_codes, v_scale,
-      pool_pos, k_fresh, v_fresh)
+    )(block_table, start, q, q_pos[:, None], q_pos[:, None], k_codes, k_scale,
+      v_codes, v_scale, pool_pos, k_fresh, v_fresh)
     return out[:, :, :s]

@@ -67,7 +67,9 @@ def gather_pages_ref(pool_leaf, block_table):
     layout — the paged↔dense bridge both oracles and tests rely on.
 
     pool_leaf (P, K, page, ...) or (P, page); block_table (R, nb) →
-    dense (R, K, nb·page, ...) or (R, nb·page) in block-table order."""
+    dense (R, K, nb·page, ...) or (R, nb·page) in block-table order. The
+    pool's scale (P, K, 1, page) and position (P, 1, page) leaves are passed
+    with their unit axis dropped (``k_scale[:, :, 0]``, ``pool_pos[:, 0]``)."""
     g = pool_leaf[block_table]  # (R, nb, K, page, ...) or (R, nb, page)
     if pool_leaf.ndim == 2:
         return g.reshape(g.shape[0], -1)
@@ -80,13 +82,13 @@ def paged_decode_attention_ref(q, k_codes, k_scale, v_codes, v_scale,
     """Paged oracle: gather each request's pages dense (via the block table),
     then run :func:`decode_attention_ref` with per-request causal bounds.
 
-    q (R,K,G,hd); pool codes (P,K,page,hd) int8 with scales (P,K,page);
-    pool_pos (P,page); block_table (R,nb) int32; q_pos (R,) → (R,K,G,hd)."""
+    q (R,K,G,hd); pool codes (P,K,page,hd) int8 with scales (P,K,1,page);
+    pool_pos (P,1,page); block_table (R,nb) int32; q_pos (R,) → (R,K,G,hd)."""
     kc = gather_pages_ref(k_codes, block_table)
-    ks = gather_pages_ref(k_scale, block_table)
+    ks = gather_pages_ref(k_scale[:, :, 0], block_table)
     vc = gather_pages_ref(v_codes, block_table)
-    vs = gather_pages_ref(v_scale, block_table)
-    kv_pos = gather_pages_ref(pool_pos, block_table)
+    vs = gather_pages_ref(v_scale[:, :, 0], block_table)
+    kv_pos = gather_pages_ref(pool_pos[:, 0], block_table)
     return decode_attention_ref(q, kc, ks, vc, vs, kv_pos, q_pos)
 
 
@@ -95,8 +97,8 @@ def paged_prefill_attention_ref(q, k_codes, k_scale, v_codes, v_scale,
                                 k_fresh, v_fresh):
     """Dense oracle for the paged PREFILL page-walk kernel.
 
-    q (R,K,S,G,hd); pool codes (P,K,page,hd) int8 with scales (P,K,page);
-    pool_pos (P,page); block_table (R,nb); q_pos (R,S) per-token positions
+    q (R,K,S,G,hd); pool codes (P,K,page,hd) int8 with scales (P,K,1,page);
+    pool_pos (P,1,page); block_table (R,nb); q_pos (R,S) per-token positions
     (-1 pads); start (R,) each row's first in-call position; fresh k/v
     (R,K,S,hd) full precision → (R,K,S,G,hd) f32.
 
@@ -108,9 +110,9 @@ def paged_prefill_attention_ref(q, k_codes, k_scale, v_codes, v_scale,
     hd = q.shape[-1]
     kd = gather_pages_ref(k_codes, block_table)  # (R, K, Sp, hd)
     vd = gather_pages_ref(v_codes, block_table)
-    ks = gather_pages_ref(k_scale, block_table)
-    vs = gather_pages_ref(v_scale, block_table)
-    hist_pos = gather_pages_ref(pool_pos, block_table)  # (R, Sp)
+    ks = gather_pages_ref(k_scale[:, :, 0], block_table)
+    vs = gather_pages_ref(v_scale[:, :, 0], block_table)
+    hist_pos = gather_pages_ref(pool_pos[:, 0], block_table)  # (R, Sp)
     k_hist = kd.astype(jnp.float32) * ks[..., None]
     v_hist = vd.astype(jnp.float32) * vs[..., None]
     k_all = jnp.concatenate([k_hist, k_fresh.astype(jnp.float32)], axis=2)
@@ -147,11 +149,11 @@ def varlen_attention_ref(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
     slu = jnp.maximum(tok_slot, 0)
     kd = gather_pages_ref(k_codes, block_table)  # (R, K, Sp, hd)
     vd = gather_pages_ref(v_codes, block_table)
-    ks = gather_pages_ref(k_scale, block_table)
-    vs = gather_pages_ref(v_scale, block_table)
+    ks = gather_pages_ref(k_scale[:, :, 0], block_table)
+    vs = gather_pages_ref(v_scale[:, :, 0], block_table)
     k_hist = (kd.astype(jnp.float32) * ks[..., None])[slu]  # (T, K, Sp, hd)
     v_hist = (vd.astype(jnp.float32) * vs[..., None])[slu]
-    hist_pos = gather_pages_ref(pool_pos, block_table)[slu]  # (T, Sp)
+    hist_pos = gather_pages_ref(pool_pos[:, 0], block_table)[slu]  # (T, Sp)
     ok_hist = ((tok_slot[:, None] >= 0) & (hist_pos >= 0)
                & (hist_pos < start[slu][:, None]))  # (T, Sp)
     ok_fresh = ((tok_slot[None, :] == tok_slot[:, None])

@@ -19,7 +19,7 @@ def _ts_kernel(tau_ref, x_ref, below_ref, mask_ref, count_ref):
     mask = jnp.abs(x) >= tau
     below_ref[...] = jnp.where(mask, 0.0, x)
     mask_ref[...] = mask.astype(jnp.uint8)
-    count_ref[0, 0] = jnp.sum(mask.astype(jnp.int32))
+    count_ref[...] = jnp.full(count_ref.shape, jnp.sum(mask.astype(jnp.int32)))
 
 
 def ts_mask(x: jax.Array, tau: float, block_t: int = 8,
@@ -29,7 +29,9 @@ def ts_mask(x: jax.Array, tau: float, block_t: int = 8,
     assert t % block_t == 0
     grid = (t // block_t,)
     tau_arr = jnp.full((1, 1), tau, jnp.float32)
-    return pl.pallas_call(
+    # counts carry a unit axis so each (1, 1) count block spans its array's
+    # last two dimensions, as the TPU compiler requires
+    below, mask, counts = pl.pallas_call(
         _ts_kernel,
         grid=grid,
         in_specs=[
@@ -39,12 +41,13 @@ def ts_mask(x: jax.Array, tau: float, block_t: int = 8,
         out_specs=[
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, d), jnp.float32),
             jax.ShapeDtypeStruct((t, d), jnp.uint8),
-            jax.ShapeDtypeStruct((t // block_t, 1), jnp.int32),
+            jax.ShapeDtypeStruct((t // block_t, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(tau_arr, x)
+    return below, mask, counts[:, 0]

@@ -32,8 +32,8 @@ grid row:
 Operand layout (pool exactly as ``serving.kv_pool`` holds it):
 
   q            (K, T, G, hd)         flat token batch, kv-head-major
-  k/v_codes    (P, K, page, hd) int8  k/v_scale (P, K, page) f32
-  pool_pos     (P, page) int32        (-1 = empty slot)
+  k/v_codes    (P, K, page, hd) int8  k/v_scale (P, K, 1, page) f32
+  pool_pos     (P, 1, page) int32     (-1 = empty slot)
   block_table  (R, nb) int32          (unused entries → trash page 0)
   q_pos        (T,) int32             per-token absolute positions (-1 pad)
   tok_slot     (T,) int32             per-token slot ids (-1 pad)
@@ -41,6 +41,9 @@ Operand layout (pool exactly as ``serving.kv_pool`` holds it):
                                       (2^30 for slots absent from the call)
   k/v_fresh    (K, T, hd)             this call's keys/values, full precision
   out          (K, T, G, hd) f32
+
+The pool leaves' unit axis before ``page`` keeps each per-page block's
+last two dimensions whole, as the TPU compiler requires.
 
 Grid: one program per (kv_head, minor step); total page visits are
 ``R * nb`` — identical to the decode kernel's ``(R, K, nb)`` grid, so the
@@ -101,16 +104,15 @@ def _kernel(nsteps: int, nb: int, t: int, scale: float, bt_ref, start_ref,
     def _pool_page():
         slot = si // nb  # the slot this walk step serves
         start = start_ref[slot]
-        k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-        kv_pos = pos_ref[0]  # (page,)
+        k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].T  # (page, hd)
+        v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].T
+        kv_pos = pos_ref[0]  # (1, page)
         # history only, and only for this step's slot: positions below the
         # slot's first in-call position (this call's own tokens live in the
         # pool too — post-update — and are attended as fresh keys instead;
         # every row of the slot sits at >= start, so per-row causality is
         # implied). Pad rows carry slot -1 and match nothing.
-        valid = ((sl[:, None] == slot) & (kv_pos[None, :] >= 0)
-                 & (kv_pos[None, :] < start))
+        valid = ((sl[:, None] == slot) & (kv_pos >= 0) & (kv_pos < start))
         _fold(q2, k, v, valid, g, m_ref, l_ref, acc_ref)
 
     @pl.when(si == nsteps)
@@ -140,7 +142,7 @@ def varlen_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
     p, _, page, _ = k_codes.shape
     r, nb = block_table.shape
     assert q_pos.shape == (t,) and tok_slot.shape == (t,)
-    assert start.shape == (r,) and pool_pos.shape == (p, page)
+    assert start.shape == (r,) and pool_pos.shape == (p, 1, page)
     assert k_fresh.shape == (kh, t, hd) and v_fresh.shape == k_fresh.shape
     nsteps = r * nb
     scale = 1.0 / (hd ** 0.5)
@@ -164,14 +166,14 @@ def varlen_attention(q, k_codes, k_scale, v_codes, v_scale, pool_pos,
             pl.BlockSpec((1, t), lambda j, si, bt, st: (0, 0)),
             pl.BlockSpec((1, 1, page, hd),
                          lambda j, si, bt, st: (bt[page_of(si)], j, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda j, si, bt, st: (bt[page_of(si)], j, 0)),
+            pl.BlockSpec((1, 1, 1, page),
+                         lambda j, si, bt, st: (bt[page_of(si)], j, 0, 0)),
             pl.BlockSpec((1, 1, page, hd),
                          lambda j, si, bt, st: (bt[page_of(si)], j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page),
+                         lambda j, si, bt, st: (bt[page_of(si)], j, 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda j, si, bt, st: (bt[page_of(si)], j, 0)),
-            pl.BlockSpec((1, page),
-                         lambda j, si, bt, st: (bt[page_of(si)], 0)),
+                         lambda j, si, bt, st: (bt[page_of(si)], 0, 0)),
             pl.BlockSpec((1, t, hd), lambda j, si, bt, st: (j, 0, 0)),
             pl.BlockSpec((1, t, hd), lambda j, si, bt, st: (j, 0, 0)),
         ],

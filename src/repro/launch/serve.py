@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.channel import ChannelConfig
 from repro.core.opsc import OPSCConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import RuntimeOpts, init_params
 from repro.serving.engine import Engine
 from repro.serving.split_engine import SplitEngine
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--qw-front", type=int, default=8)
     ap.add_argument("--deadline-ms", type=float, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.tiny:

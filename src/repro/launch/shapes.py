@@ -292,11 +292,12 @@ def paged_decode_target(cfg: ArchConfig, shape: ShapeSpec, mesh,
                    (None, dax, None, None, None)),
             v=leaf((nb, num_pages, kh, page, hd), jnp.int8,
                    (None, dax, None, None, None)),
-            k_scale=leaf((nb, num_pages, kh, page), jnp.float32,
-                         (None, dax, None, None)),
-            v_scale=leaf((nb, num_pages, kh, page), jnp.float32,
-                         (None, dax, None, None)),
-            pos=leaf((nb, num_pages, page), jnp.int32, (None, dax, None)),
+            k_scale=leaf((nb, num_pages, kh, 1, page), jnp.float32,
+                         (None, dax, None, None, None)),
+            v_scale=leaf((nb, num_pages, kh, 1, page), jnp.float32,
+                         (None, dax, None, None, None)),
+            pos=leaf((nb, num_pages, 1, page), jnp.int32,
+                     (None, dax, None, None)),
             block_table=leaf((nb, b, maxb), jnp.int32, (None, None, None)),
         )
         for _ in cfg.pattern)

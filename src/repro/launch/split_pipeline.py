@@ -34,7 +34,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -183,15 +182,15 @@ def pipeline_decode_sharded(cfg: ArchConfig, opts: RuntimeOpts, mesh,
         return jax.tree_util.tree_map(lambda _: P("pod"), tree)
 
     def wrapped(blocks, other_params, tokens, caches, pos):
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(blocks_spec(blocks), jax.tree_util.tree_map(
                 lambda _: P(), other_params), P(), blocks_spec(caches), P()),
             out_specs=(P(), blocks_spec(caches)),
             # manual over 'pod' only; any other mesh axes stay under GSPMD
-            auto=frozenset(mesh.axis_names) - {"pod"},
-            check_rep=False,
+            axis_names={"pod"},
+            check_vma=False,
         )(blocks, other_params, tokens, caches, pos)
 
     return wrapped

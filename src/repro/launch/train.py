@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.data.pipeline import ZipfMarkov, lm_loader
 from repro.launch import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import RuntimeOpts
 from repro.training.checkpoint import save_checkpoint
 from repro.training.optimizer import AdamWConfig, adamw_init
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--mesh", default=None, help="e.g. 2x4 (data x model)")
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.tiny:

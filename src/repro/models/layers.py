@@ -132,8 +132,8 @@ class PagedKVCache:
     ``serving.kv_pool``; this type is what flows through the block scan and
     what ``attention_layer`` routes on.
 
-      k / v        (P, K, page, hd) int8    k/v_scale (P, K, page) f32
-      pos          (P, page) int32          (-1 = empty slot)
+      k / v        (P, K, page, hd) int8    k/v_scale (P, K, 1, page) f32
+      pos          (P, 1, page) int32       (-1 = empty slot)
       block_table  (R, max_blocks) int32    (page ids; 0 = reserved trash
                                              page for pads/inactive rows)
 
@@ -285,15 +285,17 @@ def paged_cache_update(cache: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
     kc, ks = _quantize_kv(k_new)  # (R, S_new, K, hd), (R, S_new, K, 1)
     vc, vs = _quantize_kv(v_new)
 
-    def put(buf, val):  # buf (P, K, page[, hd]); val (R, S_new, K[, hd])
+    def put(buf, val):  # buf (P, K, page, hd); val (R, S_new, K, hd)
         flat = val.reshape((r * s_new,) + val.shape[2:])
         return buf.at[pr, :, sl].set(flat.astype(buf.dtype))
 
-    new_pos = cache.pos.at[pr, sl].set(
+    def put_scale(buf, val):  # buf (P, K, 1, page); val (R, S_new, K, 1)
+        return buf.at[pr, :, 0, sl].set(val.reshape(r * s_new, -1))
+
+    new_pos = cache.pos.at[pr, 0, sl].set(
         jnp.where(valid, positions, -1).reshape(-1))
     return PagedKVCache(put(cache.k, kc), put(cache.v, vc),
-                        put(cache.k_scale, ks[..., 0]),
-                        put(cache.v_scale, vs[..., 0]),
+                        put_scale(cache.k_scale, ks), put_scale(cache.v_scale, vs),
                         new_pos, cache.block_table)
 
 
@@ -473,7 +475,8 @@ def _head_shard(head_axis, head_shards: int, kh: int):
 
 def _slice_cache_heads(cache: PagedKVCache, off, kh_loc: int) -> PagedKVCache:
     """Slice the pool leaves' kv-head axis (axis 1 of the per-block
-    (P, K, page[, hd]) leaves) down to one shard's head group."""
+    (P, K, page, hd) and (P, K, 1, page) leaves) down to one shard's head
+    group."""
     sl = lambda a: jax.lax.dynamic_slice_in_dim(a, off, kh_loc, axis=1)
     return PagedKVCache(sl(cache.k), sl(cache.v), sl(cache.k_scale),
                         sl(cache.v_scale), cache.pos, cache.block_table)
@@ -486,9 +489,9 @@ def _gather_dense_kv(cache: PagedKVCache):
 
     kd = gather_pages_ref(cache.k, cache.block_table)  # (R, K, Sp, hd)
     vd = gather_pages_ref(cache.v, cache.block_table)
-    ks = gather_pages_ref(cache.k_scale, cache.block_table)
-    vs = gather_pages_ref(cache.v_scale, cache.block_table)
-    kv_pos = gather_pages_ref(cache.pos, cache.block_table)  # (R, Sp)
+    ks = gather_pages_ref(cache.k_scale[:, :, 0], cache.block_table)
+    vs = gather_pages_ref(cache.v_scale[:, :, 0], cache.block_table)
+    kv_pos = gather_pages_ref(cache.pos[:, 0], cache.block_table)  # (R, Sp)
     k = jnp.swapaxes(kd.astype(jnp.float32) * ks[..., None], 1, 2)
     v = jnp.swapaxes(vd.astype(jnp.float32) * vs[..., None], 1, 2)
     return k, v, kv_pos
@@ -726,12 +729,14 @@ def attention_layer(params, x: jax.Array, spec, *, rope_cs, cache: KVCache | Non
         if quant_fresh is not None:
             # int8 round trip for the masked rows: bit-identical to what
             # paged_cache_update just stored for them, so attending these
-            # "fresh" keys equals reading them back from the pool
+            # "fresh" keys equals reading them back from the pool. Kept in
+            # f32 like the pool's dequantized reads: a bf16 model's k/v
+            # dtype would round the dequantized values again
             kc, ks = _quantize_kv(k)
             vc, vs = _quantize_kv(v)
             m = quant_fresh[..., None, None]  # (B, S, 1, 1)
-            k_att = jnp.where(m, kc.astype(jnp.float32) * ks, k).astype(k.dtype)
-            v_att = jnp.where(m, vc.astype(jnp.float32) * vs, v).astype(v.dtype)
+            k_att = jnp.where(m, kc.astype(jnp.float32) * ks, k)
+            v_att = jnp.where(m, vc.astype(jnp.float32) * vs, v)
         out = varlen_attention_layer(q, new_cache, k_att, v_att, spec,
                                      q_positions, token_slots,
                                      use_kernel=prefill_kernel,

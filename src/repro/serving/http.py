@@ -25,8 +25,10 @@ EOF, so no chunked-encoding framing is needed; a client that disconnects
 mid-stream is detected by EOF on its socket and the request is aborted —
 its pool pages free on the next tick.
 
-Run a demo server (tiny randomly initialized model — the serving plumbing
-is real, the weights are not)::
+Run a server on randomly initialized weights (the serving plumbing is
+real, the weights are not). The default ``--config llama2-7b-tiny`` boots
+in seconds on a CPU; ``--config llama2-7b`` serves the published widths in
+bfloat16 and needs an accelerator::
 
     PYTHONPATH=src python -m repro.serving.http --port 8035 --max-slots 4
     curl -N localhost:8035/v1/completions -d \
@@ -41,7 +43,6 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import dataclasses
 import json
 
 from repro.core.sampling import SamplingParams
@@ -277,26 +278,40 @@ class ServingHTTPServer:
 # ------------------------------------------------------------------ CLI
 
 
-def _build_server(args):
-    """A demo LLMServer on a tiny randomly initialized model — boots in
-    seconds on CPU; the serving layer under test is real."""
+def build_model(config: str, seed: int = 0, out_shardings=None):
+    """``(cfg, params, opts)`` for a registered config name, with random
+    weights drawn from ``seed``.
+
+    ``<name>-tiny`` is the CPU demo: the reduced widths of
+    ``ArchConfig.tiny`` in float32. Any other name is the published widths
+    in bfloat16, drawn by one compiled ``init_params`` so that no full-size
+    float32 tensor is ever materialized. ``out_shardings`` places the
+    weights where they will live (e.g. replicated over a serving mesh)."""
     import jax
+    import jax.numpy as jnp
 
     from repro.configs import get_config
-    from repro.models.transformer import RuntimeOpts, init_params
+    from repro.models.transformer import RuntimeOpts, init_params_jit
 
-    cfg = dataclasses.replace(get_config(args.config).tiny(),
-                              vocab_size=args.vocab)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
-    opts = RuntimeOpts(q_chunk=16, kv_chunk=16, remat=False,
-                       quantized_kv=True, moe_capacity_factor=0.0)
+    cfg = get_config(config)
+    tiny = config.endswith("-tiny")
+    params = init_params_jit(cfg, jax.random.PRNGKey(seed),
+                             jnp.float32 if tiny else jnp.bfloat16,
+                             out_shardings)
+    chunk = dict(q_chunk=16, kv_chunk=16) if tiny else {}
+    opts = RuntimeOpts(remat=False, quantized_kv=True, moe_capacity_factor=0.0,
+                       **chunk)
+    return cfg, params, opts
+
+
+def _build_server(args):
     from repro.serving.api import LLMServer
 
+    cfg, params, opts = build_model(args.config, args.seed)
     kwargs: dict = {}
     if args.backend == "paged":
         kwargs = dict(deployment=args.deployment, num_pages=args.num_pages,
-                      page_size=4, max_slots=args.max_slots,
-                      auto_prefix=args.auto_prefix)
+                      max_slots=args.max_slots, auto_prefix=args.auto_prefix)
     return LLMServer(cfg, params, opts, backend=args.backend, **kwargs)
 
 
@@ -324,14 +339,18 @@ def main(argv=None) -> None:
                    choices=("paged", "fused"))
     p.add_argument("--deployment", default="fused",
                    choices=("fused", "sharded", "disaggregated"))
-    p.add_argument("--config", default="llama2-7b")
-    p.add_argument("--vocab", type=int, default=64)
+    p.add_argument("--config", default="llama2-7b-tiny",
+                   help="registered config; a -tiny suffix runs its reduced "
+                        "float32 CPU variant")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--num-pages", type=int, default=64)
     p.add_argument("--max-slots", type=int, default=4)
     p.add_argument("--max-queue-depth", type=int, default=64)
     p.add_argument("--auto-prefix", action="store_true")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     try:
         asyncio.run(_amain(args))
     except KeyboardInterrupt:

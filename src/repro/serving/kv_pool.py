@@ -16,9 +16,12 @@ Layout per pattern position (leading ``num_blocks`` axis consumed by the
 transformer's block scan, exactly like the dense caches):
 
   k / v          (nb, P, K, page, hd) int8
-  k/v_scale      (nb, P, K, page)     f32
-  pos            (nb, P, page)        int32   (-1 = empty)
+  k/v_scale      (nb, P, K, 1, page)  f32
+  pos            (nb, P, 1, page)     int32   (-1 = empty)
   block_table    (nb, R, max_blocks)  int32   (host-owned, installed per call)
+
+(The unit axis before ``page`` keeps the kernels' per-page blocks whole in
+their last two dimensions, which the TPU compiler requires.)
 
 Page 0 is RESERVED as the trash page: block-table entries of inactive rows
 and pad-token writes point at it, its positions stay -1, and the kernel's
@@ -92,6 +95,18 @@ def uniform_page_count(seq_len: int, page_size: int) -> int:
     (``kernels.decode_attention.padded_cache_len(s, page_size, uniform=True)``
     is the same rounding in token units)."""
     return max(1, -(-seq_len // page_size))
+
+
+def page_bytes(cfg: ArchConfig, page_size: int = DEFAULT_PAGE_SIZE,
+               num_blocks: int | None = None) -> int:
+    """Device BYTES of ONE page across every attention layer of ``cfg``
+    (int8 k/v codes, f32 per-token scales, int32 positions) — what sizes a
+    pool to the memory the weights leave."""
+    m = cfg.pattern[0].mixer
+    kh, hd, ps = m.num_kv_heads, m.head_dim, page_size
+    nb = cfg.num_blocks if num_blocks is None else num_blocks
+    per_layer = 2 * kh * ps * hd * 1 + 2 * kh * ps * 4 + ps * 4
+    return per_layer * nb * len(cfg.pattern)
 
 
 @dataclasses.dataclass
@@ -186,9 +201,9 @@ class PagedKVPool:
             PagedKVCache(
                 k=jnp.zeros((nb, p, kh, ps, hd), jnp.int8),
                 v=jnp.zeros((nb, p, kh, ps, hd), jnp.int8),
-                k_scale=jnp.zeros((nb, p, kh, ps), jnp.float32),
-                v_scale=jnp.zeros((nb, p, kh, ps), jnp.float32),
-                pos=jnp.full((nb, p, ps), -1, jnp.int32),
+                k_scale=jnp.zeros((nb, p, kh, 1, ps), jnp.float32),
+                v_scale=jnp.zeros((nb, p, kh, 1, ps), jnp.float32),
+                pos=jnp.full((nb, p, 1, ps), -1, jnp.int32),
                 block_table=jnp.zeros((nb, max_requests, self.max_blocks),
                                       jnp.int32),
             )
@@ -694,7 +709,8 @@ class PagedKVPool:
         outs = []
         for c in self._caches:
             leaves = []
-            for leaf in (c.k, c.v, c.k_scale, c.v_scale, c.pos):
+            for leaf in (c.k, c.v, c.k_scale[:, :, :, 0], c.v_scale[:, :, :, 0],
+                         c.pos[:, :, 0]):
                 g = jnp.stack([gather_pages_ref(leaf[i], bt)[0]
                                for i in range(self.nb)])
                 leaves.append(g)
@@ -705,9 +721,7 @@ class PagedKVPool:
 
     def page_bytes(self) -> int:
         """Device BYTES of ONE page across every covered layer."""
-        kh, hd, ps = self.kv_heads, self.head_dim, self.page_size
-        per_layer = 2 * kh * ps * hd * 1 + 2 * kh * ps * 4 + ps * 4
-        return per_layer * self.num_layers
+        return page_bytes(self.cfg, self.page_size, self.nb)
 
     def page_bytes_in_use(self) -> int:
         """Page-granular occupancy in BYTES: what the allocated pages

@@ -900,6 +900,32 @@ class Scheduler:
                              jnp.asarray(self._op_bias))
         return self._dev_ops
 
+    def lower_ticks(self) -> dict:
+        """The jitted tick programs this tick mode dispatches through the
+        paged attention kernels, lowered (not compiled) at their serving
+        shapes: ``{name: jax.stages.Lowered}``. ``as_text()`` shows what a
+        tick runs — e.g. whether the Pallas kernels are in it
+        (``tpu_custom_call`` on a TPU)."""
+        r = self.max_slots
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        keys, temp, tk, tp, bias = self._device_ops()
+        ops = dict(keys=keys, temp=temp, tk=tk, tp=tp, bias=bias)
+        caches = self.pool.device_caches()
+        if self.tick_mode == "packed":
+            t = self.token_budget
+            return {"packed": self._packed.lower(
+                self.params, i32(1, t), caches=caches, positions=i32(1, t),
+                slots=i32(1, t), logit_rows=i32(r),
+                quant_fresh=jax.ShapeDtypeStruct((1, t), jnp.bool_),
+                t=i32(r), **ops)}
+        c = self.prefill_chunk
+        return {"decode": self._decode.lower(
+                    self.params, i32(r, 1), caches=caches, pos=i32(r),
+                    t=i32(r), **ops),
+                "prefill_chunk": self._prefill_shared.lower(
+                    self.params, i32(r, c), caches=caches,
+                    positions=i32(r, c))}
+
     def _register_shape(self, *shape) -> None:
         """Track every distinct jitted call shape the scheduler dispatches —
         ``stats.compiled_shapes`` is the compile-count the chunked mode

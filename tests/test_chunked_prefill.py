@@ -39,20 +39,20 @@ def _prefill_fixture(rng, hist_lens, suf_lens, kh=2, g=2, page=4, hd=32,
     mask is really exercised against double counting."""
     kc = np.asarray(rng.integers(-127, 128, (p, kh, page, hd)), np.int8)
     vc = np.asarray(rng.integers(-127, 128, (p, kh, page, hd)), np.int8)
-    ks = np.asarray(rng.uniform(0.005, 0.02, (p, kh, page)), np.float32)
-    vs = np.asarray(rng.uniform(0.005, 0.02, (p, kh, page)), np.float32)
+    ks = np.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), np.float32)
+    vs = np.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), np.float32)
     r = len(hist_lens)
     totals = [h + s for h, s in zip(hist_lens, suf_lens)]
     maxb = max(-(-t // page) for t in totals)
     bt = np.zeros((r, maxb), np.int32)
-    pool_pos = np.full((p, page), -1, np.int32)
+    pool_pos = np.full((p, 1, page), -1, np.int32)
     nxt = 1
     for i, t in enumerate(totals):
         for b in range(-(-t // page)):
             bt[i, b] = nxt
             nxt += 1
         for tok in range(t):  # history AND this call's tokens stored
-            pool_pos[bt[i, tok // page], tok % page] = tok
+            pool_pos[bt[i, tok // page], 0, tok % page] = tok
     assert nxt <= p
     s = max(suf_lens)
     q_pos = np.full((r, s), -1, np.int32)

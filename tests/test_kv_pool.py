@@ -289,11 +289,11 @@ def test_cow_copy_scrubs_foreign_positions():
     b = pool.admit(7, prefix=h)
     cow = int(pool.block_tables[b][1])
     for c in pool._caches:
-        got = np.asarray(c.pos[:, cow])
+        got = np.asarray(c.pos[:, cow, 0])
         np.testing.assert_array_equal(got, np.tile([4, 5, -1, -1],
                                                    (pool.nb, 1)))
         # the original page is untouched
-        np.testing.assert_array_equal(np.asarray(c.pos[:, p1]),
+        np.testing.assert_array_equal(np.asarray(c.pos[:, p1, 0]),
                                       np.tile([4, 5, 6, 7], (pool.nb, 1)))
 
 
@@ -397,9 +397,9 @@ def test_truncate_rolls_back_rejected_tail():
     assert pool.pages_in_use == used  # rollback never frees pages
     for c in pool._caches:
         np.testing.assert_array_equal(
-            np.asarray(c.pos[:, p0]), np.tile(np.arange(4), (pool.nb, 1)))
+            np.asarray(c.pos[:, p0, 0]), np.tile(np.arange(4), (pool.nb, 1)))
         np.testing.assert_array_equal(
-            np.asarray(c.pos[:, p1]), np.tile([4, 5, -1, -1], (pool.nb, 1)))
+            np.asarray(c.pos[:, p1, 0]), np.tile([4, 5, -1, -1], (pool.nb, 1)))
         assert int(jnp.max(c.pos[:, p2])) == -1
     # bounds: rolling back to zero or past the length is a caller bug
     with pytest.raises(ValueError, match="outside"):
@@ -430,12 +430,12 @@ def test_truncate_refuses_to_scrub_shared_pages():
     np.testing.assert_array_equal(pool.refcount, before_ref)
     np.testing.assert_array_equal(pool.lengths, before_len)
     for c in pool._caches:
-        np.testing.assert_array_equal(np.asarray(c.pos[:, p1]),
+        np.testing.assert_array_equal(np.asarray(c.pos[:, p1, 0]),
                                       np.tile([4, 5, 6, 7], (pool.nb, 1)))
     pool.release_prefix(h)
     pool.truncate(a, 6)          # exclusive again → rollback proceeds
     for c in pool._caches:
-        np.testing.assert_array_equal(np.asarray(c.pos[:, p1]),
+        np.testing.assert_array_equal(np.asarray(c.pos[:, p1, 0]),
                                       np.tile([4, 5, -1, -1], (pool.nb, 1)))
 
 

@@ -25,18 +25,18 @@ def _pool_fixture(rng, p=10, kh=2, page=16, hd=32, lens=(40, 20, 10)):
     [1 + sum(prior pages)...]; page 0 is trash (pos = -1)."""
     kc = jnp.asarray(rng.integers(-127, 128, (p, kh, page, hd)), jnp.int8)
     vc = jnp.asarray(rng.integers(-127, 128, (p, kh, page, hd)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.005, 0.02, (p, kh, page)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.005, 0.02, (p, kh, page)), jnp.float32)
+    ks = jnp.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), jnp.float32)
     maxb = max(-(-n // page) for n in lens)
     bt = np.zeros((len(lens), maxb), np.int32)
-    pool_pos = np.full((p, page), -1, np.int32)
+    pool_pos = np.full((p, 1, page), -1, np.int32)
     nxt = 1
     for r, n in enumerate(lens):
         for b in range(-(-n // page)):
             bt[r, b] = nxt
             nxt += 1
         for t in range(n):
-            pool_pos[bt[r, t // page], t % page] = t
+            pool_pos[bt[r, t // page], 0, t % page] = t
     assert nxt <= p
     return kc, ks, vc, vs, jnp.asarray(pool_pos), jnp.asarray(bt)
 
@@ -68,10 +68,10 @@ def test_paged_kernel_matches_dense_kernel():
         dense = decode_attention(
             q[r:r + 1],
             ref.gather_pages_ref(kc, bt[r:r + 1]),
-            ref.gather_pages_ref(ks, bt[r:r + 1]),
+            ref.gather_pages_ref(ks[:, :, 0], bt[r:r + 1]),
             ref.gather_pages_ref(vc, bt[r:r + 1]),
-            ref.gather_pages_ref(vs, bt[r:r + 1]),
-            ref.gather_pages_ref(pool_pos, bt[r:r + 1]),
+            ref.gather_pages_ref(vs[:, :, 0], bt[r:r + 1]),
+            ref.gather_pages_ref(pool_pos[:, 0], bt[r:r + 1]),
             jnp.int32(n - 1), block_s=16)
         np.testing.assert_allclose(np.asarray(got[r]), np.asarray(dense[0]),
                                    rtol=1e-4, atol=1e-4)

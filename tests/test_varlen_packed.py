@@ -41,20 +41,20 @@ def _varlen_fixture(rng, segs, kh=2, g=2, page=4, hd=32, p=16, pad=0):
     (slot -1, position -1) close the fixed-budget buffer's tail."""
     kc = np.asarray(rng.integers(-127, 128, (p, kh, page, hd)), np.int8)
     vc = np.asarray(rng.integers(-127, 128, (p, kh, page, hd)), np.int8)
-    ks = np.asarray(rng.uniform(0.005, 0.02, (p, kh, page)), np.float32)
-    vs = np.asarray(rng.uniform(0.005, 0.02, (p, kh, page)), np.float32)
+    ks = np.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), np.float32)
+    vs = np.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), np.float32)
     r = len(segs)
     totals = [h + n for h, n in segs]
     maxb = max(-(-t // page) for t in totals)
     bt = np.zeros((r, maxb), np.int32)
-    pool_pos = np.full((p, page), -1, np.int32)
+    pool_pos = np.full((p, 1, page), -1, np.int32)
     nxt = 1  # page 0 is the trash page
     for i, t in enumerate(totals):
         for b in range(-(-t // page)):
             bt[i, b] = nxt
             nxt += 1
         for tok in range(t):  # history AND this call's tokens stored
-            pool_pos[bt[i, tok // page], tok % page] = tok
+            pool_pos[bt[i, tok // page], 0, tok % page] = tok
     assert nxt <= p
     t_flat = sum(n for _, n in segs) + pad
     q_pos = np.full((t_flat,), -1, np.int32)
@@ -130,9 +130,9 @@ def test_varlen_pure_decode_matches_decode_oracle():
     kf, vf = np.asarray(kf).copy(), np.asarray(vf).copy()
     for t, (h, _) in enumerate(segs):
         pg, off = bt[t, h // page], h % page
-        kf[:, t] = np.asarray(kc)[pg, :, off] * np.asarray(ks)[pg, :, off,
+        kf[:, t] = np.asarray(kc)[pg, :, off] * np.asarray(ks)[pg, :, 0, off,
                                                               None]
-        vf[:, t] = np.asarray(vc)[pg, :, off] * np.asarray(vs)[pg, :, off,
+        vf[:, t] = np.asarray(vc)[pg, :, off] * np.asarray(vs)[pg, :, 0, off,
                                                                None]
     got = ops.varlen_attention(q, kc, ks, vc, vs, pp, bt, qp, sl,
                                jnp.asarray(kf), jnp.asarray(vf))
