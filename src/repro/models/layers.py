@@ -720,8 +720,9 @@ def attention_layer(params, x: jax.Array, spec, *, rope_cs, cache: KVCache | Non
     if cache is not None:
         if isinstance(cache, PagedKVCache):
             # paged pool: positions are per-token (ragged prefill pads < 0)
-            new_cache = paged_cache_update(cache, k, v, q_positions,
-                                           slots=token_slots)
+            with jax.named_scope("kv_write"):
+                new_cache = paged_cache_update(cache, k, v, q_positions,
+                                               slots=token_slots)
         else:
             new_cache = cache_update(cache, k, v, pos, spec.sliding_window)
     if token_slots is not None and isinstance(new_cache, PagedKVCache):

@@ -225,13 +225,15 @@ def _apply_layer(cfg, ls: LayerSpec, p, x, *, rope_cs, q_positions, cache, pos,
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if isinstance(ls.mixer, AttnSpec):
-        out, new_cache = L.attention_layer(
-            p["mixer"], h, ls.mixer, rope_cs=rope_cs, cache=cache, pos=pos,
-            q_positions=q_positions, q_chunk=opts.q_chunk, kv_chunk=opts.kv_chunk,
-            decode=decode, attend_cache=attend_cache,
-            prefill_kernel=opts.paged_prefill_kernel, token_slots=token_slots,
-            quant_fresh=quant_fresh, head_axis=opts.head_axis,
-            head_shards=opts.head_shards)
+        with jax.named_scope("attention"):
+            out, new_cache = L.attention_layer(
+                p["mixer"], h, ls.mixer, rope_cs=rope_cs, cache=cache,
+                pos=pos, q_positions=q_positions, q_chunk=opts.q_chunk,
+                kv_chunk=opts.kv_chunk, decode=decode,
+                attend_cache=attend_cache,
+                prefill_kernel=opts.paged_prefill_kernel,
+                token_slots=token_slots, quant_fresh=quant_fresh,
+                head_axis=opts.head_axis, head_shards=opts.head_shards)
     else:
         conv_state, ssm_state = cache if cache is not None else (None, None)
         out, new_cache = ssm_layer(p["mixer"], h, ls.mixer,
@@ -242,13 +244,15 @@ def _apply_layer(cfg, ls: LayerSpec, p, x, *, rope_cs, q_positions, cache, pos,
                 lambda new, old: new.astype(old.dtype), new_cache, cache)
     x = x + out
     if ls.ffn is not None:
-        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        if isinstance(ls.ffn, MoESpec):
-            out, aux = moe_layer(p["ffn"], h, ls.ffn,
-                                 opts.moe_capacity_factor, opts.moe_groups)
-        else:
-            out = L.mlp_layer(p["ffn"], h, ls.ffn.activation)
-        x = x + out
+        with jax.named_scope("mlp"):
+            h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+            if isinstance(ls.ffn, MoESpec):
+                out, aux = moe_layer(p["ffn"], h, ls.ffn,
+                                     opts.moe_capacity_factor,
+                                     opts.moe_groups)
+            else:
+                out = L.mlp_layer(p["ffn"], h, ls.ffn.activation)
+            x = x + out
     if opts.act_bits is not None:  # uniform activation quantization baseline
         from repro.core.quant import aiq, aiq_dequant
 
@@ -307,10 +311,11 @@ def _apply_blocks_cached(cfg, blocks, x, caches, *, rope_cs, q_positions, pos,
                                     decode=decode, attend_cache=attend_cache,
                                     token_slots=token_slots,
                                     quant_fresh=quant_fresh)
-            new_caches.append(jax.tree_util.tree_map(
-                lambda full, sl: jax.lax.dynamic_update_slice_in_dim(
-                    full, sl[None].astype(full.dtype), i, axis=0),
-                caches[pi], nc))
+            with jax.named_scope("kv_write"):
+                new_caches.append(jax.tree_util.tree_map(
+                    lambda full, sl: jax.lax.dynamic_update_slice_in_dim(
+                        full, sl[None].astype(full.dtype), i, axis=0),
+                    caches[pi], nc))
         return (x, tuple(new_caches)), None
 
     nb = jax.tree_util.tree_leaves(blocks)[0].shape[0]
@@ -426,12 +431,14 @@ def paged_prefill(params, cfg: ArchConfig, tokens, caches, positions,
     ``serving.kv_pool.PagedKVPool.device_caches`` (block tables installed
     for exactly these R rows). Returns (last_logits (R, V), caches)."""
     positions = jnp.asarray(positions, jnp.int32)
-    x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
-    rope_cs = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
+        rope_cs = rope_tables(cfg, positions)
     x, caches = _apply_blocks_cached(cfg, params["blocks"], x, caches,
                                      rope_cs=rope_cs, q_positions=positions,
                                      pos=jnp.int32(0), opts=opts, decode=False)
-    logits = apply_head(cfg, params, x[:, -1:])
+    with jax.named_scope("head"):
+        logits = apply_head(cfg, params, x[:, -1:])
     return logits[:, 0], caches
 
 
@@ -450,13 +457,15 @@ def paged_prefill_shared(params, cfg: ArchConfig, tokens, caches, positions,
     attends exactly prefix + itself). Rows starting at position 0 behave
     like the plain ragged prefill. Returns (last_logits (R, V), caches)."""
     positions = jnp.asarray(positions, jnp.int32)
-    x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
-    rope_cs = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
+        rope_cs = rope_tables(cfg, positions)
     x, caches = _apply_blocks_cached(cfg, params["blocks"], x, caches,
                                      rope_cs=rope_cs, q_positions=positions,
                                      pos=jnp.int32(0), opts=opts, decode=False,
                                      attend_cache=True)
-    logits = apply_head(cfg, params, x[:, -1:])
+    with jax.named_scope("head"):
+        logits = apply_head(cfg, params, x[:, -1:])
     return logits[:, 0], caches
 
 
@@ -469,12 +478,14 @@ def paged_decode_step(params, cfg: ArchConfig, tokens, caches, pos,
     scheduler jits once for the full slot count and reuses as requests come
     and go."""
     positions = jnp.asarray(pos, jnp.int32)[:, None]  # (R, 1)
-    x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
-    rope_cs = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
+        rope_cs = rope_tables(cfg, positions)
     x, caches = _apply_blocks_cached(cfg, params["blocks"], x, caches,
                                      rope_cs=rope_cs, q_positions=positions,
                                      pos=jnp.int32(0), opts=opts, decode=True)
-    logits = apply_head(cfg, params, x)
+    with jax.named_scope("head"):
+        logits = apply_head(cfg, params, x)
     return logits[:, 0], caches
 
 
@@ -507,15 +518,17 @@ def packed_step(params, cfg: ArchConfig, tokens, caches, positions, slots,
     (logits (R, V), caches)."""
     positions = jnp.asarray(positions, jnp.int32)
     slots = jnp.asarray(slots, jnp.int32)
-    x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
-    rope_cs = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
+        rope_cs = rope_tables(cfg, positions)
     x, caches = _apply_blocks_cached(cfg, params["blocks"], x, caches,
                                      rope_cs=rope_cs, q_positions=positions,
                                      pos=jnp.int32(0), opts=opts, decode=False,
                                      token_slots=slots,
                                      quant_fresh=quant_fresh)
-    xl = jnp.take(x[0], jnp.asarray(logit_rows, jnp.int32), axis=0)  # (R, D)
-    logits = apply_head(cfg, params, xl[None])
+    with jax.named_scope("head"):
+        xl = jnp.take(x[0], jnp.asarray(logit_rows, jnp.int32), axis=0)
+        logits = apply_head(cfg, params, xl[None])  # xl: (R, D)
     return logits[0], caches
 
 
@@ -537,12 +550,15 @@ def paged_verify_step(params, cfg: ArchConfig, tokens, caches, positions,
     distribution after consuming the row's in-call tokens <= j (left-pad
     columns are garbage)."""
     positions = jnp.asarray(positions, jnp.int32)
-    x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
-    rope_cs = rope_tables(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens, None, jnp.maximum(positions, 0))
+        rope_cs = rope_tables(cfg, positions)
     x, caches = _apply_blocks_cached(cfg, params["blocks"], x, caches,
                                      rope_cs=rope_cs, q_positions=positions,
                                      pos=jnp.int32(0), opts=opts, decode=True)
-    return apply_head(cfg, params, x), caches
+    with jax.named_scope("head"):
+        logits = apply_head(cfg, params, x)
+    return logits, caches
 
 
 # --------------------------------------------------------------- sharded

@@ -59,6 +59,7 @@ from repro.serving.engine import Engine
 from repro.serving.page_transport import DisaggregatedScheduler
 from repro.serving.scheduler import Scheduler
 from repro.serving.split_engine import SplitEngine
+from repro.serving.telemetry import maybe_phase
 
 FINISH_REASONS = ("stop", "length", "abort", "deadline")
 
@@ -76,6 +77,10 @@ class TokenEvent:
     # (before temperature / top-k / top-p — core.sampling.token_logprobs);
     # None on finish markers and abort events
     logprob: float | None = None
+    # tracer-clock time the event reached the host (the end of the wait
+    # that pulled the token; for a finish marker, when it was collected);
+    # set only by the paged backend, and only when it is traced
+    host_t: float | None = None
 
 
 @dataclasses.dataclass
@@ -478,9 +483,10 @@ class PagedBackend(_RequestBook):
         self._pending_events = []
         if sched.pending:
             sched.step()
-        events += self._collect(time.perf_counter())
-        if not sched.pending:  # drained — same reclamation as run()
-            sched.release_prefixes()
+        with maybe_phase(self.telemetry, "engine.collect"):
+            events += self._collect(time.perf_counter())
+            if not sched.pending:  # drained — same reclamation as run()
+                sched.release_prefixes()
         return events
 
     def abort(self, rid: int) -> bool:
@@ -491,11 +497,13 @@ class PagedBackend(_RequestBook):
 
     def _collect(self, now: float) -> list:
         sched, events = self.scheduler, []
-        for rid, idx, tok, lp in sched.drain_events():
+        tel = self.telemetry
+        for rid, idx, tok, lp, host_t in sched.drain_events():
             m = self._metrics[rid]
             if m.ttft_s is None:
                 m.ttft_s = now - m.submit_s
-            events.append(TokenEvent(rid, idx, tok, logprob=lp))
+            events.append(TokenEvent(rid, idx, tok, logprob=lp,
+                                     host_t=host_t))
         for rid in sched.drain_finished():
             req = self._reqs[rid]
             reason = sched.finish_reasons.get(rid, "length")
@@ -514,8 +522,9 @@ class PagedBackend(_RequestBook):
             self._outputs[rid] = RequestOutput(
                 rid, req.prompt, gen, finished=True, finish_reason=reason,
                 metrics=m)
-            events.append(TokenEvent(rid, gen.shape[0], -1, finished=True,
-                                     finish_reason=reason))
+            events.append(TokenEvent(
+                rid, gen.shape[0], -1, finished=True, finish_reason=reason,
+                host_t=None if tel is None else tel.now()))
         return events
 
 

@@ -56,6 +56,7 @@ import threading
 
 from repro.core.sampling import SamplingParams
 from repro.serving.api import LLMServer, RequestOutput, TokenEvent
+from repro.serving.telemetry import maybe_phase
 
 
 class AdmissionError(RuntimeError):
@@ -258,16 +259,15 @@ class AsyncLLMServer:
     # -------------------------------------------------------- tick thread
 
     def _run(self) -> None:
+        tel = self.server.tracer
         try:
             while True:
-                while True:  # commands first: submits join the next tick
-                    try:
-                        self._exec(self._cmds.get_nowait())
-                    except queue.Empty:
-                        break
+                self._run_commands(tel)  # submits join the next tick
                 if self.server.pending:
-                    for ev in self.server.backend.step():
-                        self._dispatch(ev)
+                    events = self.server.backend.step()
+                    with maybe_phase(tel, "engine.fanout"):
+                        for ev in events:
+                            self._dispatch(ev)
                     continue
                 if self._stopping:
                     break
@@ -287,6 +287,21 @@ class AsyncLLMServer:
                 except queue.Empty:
                     break
             self._exit_fut.set_result(None)
+
+    def _run_commands(self, tel) -> None:
+        """Execute every queued command, as one ``engine.commands`` host
+        phase when traced (none is recorded when the queue is empty)."""
+        try:
+            cmd = self._cmds.get_nowait()
+        except queue.Empty:
+            return
+        with maybe_phase(tel, "engine.commands"):
+            while True:
+                self._exec(cmd)
+                try:
+                    cmd = self._cmds.get_nowait()
+                except queue.Empty:
+                    return
 
     def _exec(self, cmd) -> None:
         fn, fut = cmd

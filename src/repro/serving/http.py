@@ -18,6 +18,12 @@ telemetry precedent extends to networking), four endpoints:
 ``/healthz``         GET   liveness + queue depth (503 once shut down).
 ================  ======  =====================================================
 
+With a tracer on the wrapped :class:`~repro.serving.api.LLMServer`
+(``telemetry=``), the service records one ``http.request`` span per
+completion request (request read → ``[DONE]`` or error) and one
+``sse.write`` span per streamed frame (token on the host → socket
+drained), each on the request's ``http:<rid>`` track.
+
 Error mapping: full admission queue → **429** with ``Retry-After``;
 engine shut down → **503**; malformed request → **400**; unknown route →
 **404**. Streaming responses send ``Connection: close`` and terminate by
@@ -110,13 +116,15 @@ class ServingHTTPServer:
     """The service layer: routes HTTP requests onto one
     :class:`AsyncLLMServer`. ``port=0`` binds an ephemeral port (read
     ``self.port`` after :meth:`start` — how the tests and the load-smoke
-    CI job avoid port collisions)."""
+    CI job avoid port collisions). The front end's spans go to the
+    tracer of the ``LLMServer`` the engine wraps, if it has one."""
 
     def __init__(self, engine: AsyncLLMServer, host: str = "127.0.0.1",
                  port: int = 0):
         self.engine = engine
         self.host = host
         self.port = port
+        self.tracer = engine.server.tracer
         self._server: asyncio.AbstractServer | None = None
 
     async def start(self) -> None:
@@ -147,6 +155,7 @@ class ServingHTTPServer:
             req = await self._read_request(reader)
             if req is None:
                 return
+            t_read = self.tracer.now() if self.tracer is not None else None
             method, path, body = req
             if path == "/healthz" and method == "GET":
                 code = 503 if self.engine.closed else 200
@@ -159,7 +168,7 @@ class ServingHTTPServer:
                 ok = await self.engine.abort(int(body["rid"]))
                 await self._json(writer, 200, {"aborted": ok})
             elif path == "/v1/completions" and method == "POST":
-                await self._completions(reader, writer, body)
+                await self._completions(reader, writer, body, t_read)
             else:
                 await self._json(writer, 404,
                                  {"error": f"no route {method} {path}"})
@@ -196,34 +205,43 @@ class ServingHTTPServer:
         body = json.loads(await reader.readexactly(n)) if n else {}
         return method, path, body
 
-    async def _completions(self, reader, writer, body: dict) -> None:
-        prompt = body.get("prompt")
-        if not isinstance(prompt, list) or not prompt:
-            raise ValueError("'prompt' must be a non-empty token-id list")
-        sp = _parse_sampling(body)
+    async def _completions(self, reader, writer, body: dict,
+                           t_read: float | None) -> None:
+        rid = None
         try:
-            rid = await self.engine.submit(prompt, sp)
-        except AdmissionError as e:
-            await self._json(writer, 429, {"error": str(e)},
-                             extra_headers=(("Retry-After", "1"),))
-            return
-        except EngineClosedError as e:
-            await self._json(writer, 503, {"error": str(e)})
-            return
-        if body.get("stream"):
-            await self._stream_sse(reader, writer, rid)
-        else:
-            events = [ev async for ev in self.engine.stream(rid)]
-            out = await self.engine.result(rid)
-            await self._json(writer, 200, {
-                "rid": rid,
-                "tokens": [int(t) for t in out.tokens],
-                "logprobs": [ev.logprob for ev in events
-                             if not ev.finished],
-                "finish_reason": out.finish_reason,
-                "metrics": {"ttft_s": out.metrics.ttft_s,
-                            "e2e_s": out.metrics.e2e_s},
-            })
+            prompt = body.get("prompt")
+            if not isinstance(prompt, list) or not prompt:
+                raise ValueError("'prompt' must be a non-empty token-id list")
+            sp = _parse_sampling(body)
+            try:
+                rid = await self.engine.submit(prompt, sp)
+            except AdmissionError as e:
+                await self._json(writer, 429, {"error": str(e)},
+                                 extra_headers=(("Retry-After", "1"),))
+                return
+            except EngineClosedError as e:
+                await self._json(writer, 503, {"error": str(e)})
+                return
+            if body.get("stream"):
+                await self._stream_sse(reader, writer, rid)
+            else:
+                events = [ev async for ev in self.engine.stream(rid)]
+                out = await self.engine.result(rid)
+                await self._json(writer, 200, {
+                    "rid": rid,
+                    "tokens": [int(t) for t in out.tokens],
+                    "logprobs": [ev.logprob for ev in events
+                                 if not ev.finished],
+                    "finish_reason": out.finish_reason,
+                    "metrics": {"ttft_s": out.metrics.ttft_s,
+                                "e2e_s": out.metrics.e2e_s},
+                })
+        finally:
+            tel = self.tracer
+            if tel is not None:  # off the tick thread: explicit times, tick
+                tel.add_span("http.request", t_read, tel.now(),
+                             track="http" if rid is None else f"http:{rid}",
+                             rid=rid, tick=None)
 
     async def _stream_sse(self, reader, writer, rid: int) -> None:
         writer.write(b"HTTP/1.1 200 OK\r\n"
@@ -251,6 +269,11 @@ class ServingHTTPServer:
                     return
                 writer.write(sse_frame(_event_json(ev)))
                 await writer.drain()
+                tel = self.tracer
+                if tel is not None and ev.host_t is not None:
+                    tel.add_span("sse.write", ev.host_t, tel.now(),
+                                 track=f"http:{rid}", rid=rid, tick=None,
+                                 index=ev.index)
                 if ev.finished:
                     writer.write(SSE_DONE)
                     await writer.drain()

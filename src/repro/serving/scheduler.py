@@ -130,6 +130,7 @@ from repro.models.transformer import (RuntimeOpts, packed_step,
 from repro.serving.kv_pool import (DEFAULT_PAGE_SIZE, PagedKVPool,
                                    PoolExhaustedError, SharedPrefix)
 from repro.serving.page_transport import HostSwapTransport
+from repro.serving.telemetry import maybe_phase
 
 # the adaptive-prefill ladder ``prefill_chunk="auto"`` expands to: three
 # compiled chunk shapes, picked per tick by batch composition (see
@@ -472,6 +473,9 @@ class Scheduler:
         # consumers would each see a disjoint, useless half of the stream)
         self._events: list = []
         self._finished: list = []
+        # traced only: the end of the wait that pulled the tick's tokens to
+        # the host, stamped on each event (None untraced)
+        self._ready_t: float | None = None
         self._emit_lock = threading.Lock()
         self._step_guard = threading.Lock()
         # per-slot sampling operands, updated at admit/evict so every tick
@@ -519,8 +523,9 @@ class Scheduler:
             # the device — only the sampled token ids (and their logprobs)
             # cross to the host
             logits, new_caches = decode_fn(params, tokens, caches, pos)
-            toks, lps = sample_tokens_with_logprobs(logits, keys, t,
-                                                    temp, tk, tp, bias)
+            with jax.named_scope("sample"):
+                toks, lps = sample_tokens_with_logprobs(logits, keys, t,
+                                                        temp, tk, tp, bias)
             return toks, lps, new_caches
 
         self._decode = jax.jit(decode_sample)
@@ -536,8 +541,9 @@ class Scheduler:
             # from the pool (bit-identity with the chunked/wave ticks)
             logits, new_caches = packed_fn(params, tokens, caches, positions,
                                            slots, logit_rows, quant_fresh)
-            toks, lps = sample_tokens_with_logprobs(logits, keys, t,
-                                                    temp, tk, tp, bias)
+            with jax.named_scope("sample"):
+                toks, lps = sample_tokens_with_logprobs(logits, keys, t,
+                                                        temp, tk, tp, bias)
             return toks, lps, new_caches
 
         self._packed = jax.jit(packed_sample)
@@ -560,9 +566,11 @@ class Scheduler:
             # to generation-index order, then draft acceptance — all ONE
             # jitted function; only accepted tokens cross to the host
             logits, new_caches = verify_fn(params, tokens, caches, positions)
-            logits = jnp.take_along_axis(logits, gather[:, :, None], axis=1)
-            out, n, lps = speculative_verify(draft, draft_len, logits,
-                                             keys, t0, temp, tk, tp, bias)
+            with jax.named_scope("sample"):
+                logits = jnp.take_along_axis(logits, gather[:, :, None],
+                                             axis=1)
+                out, n, lps = speculative_verify(draft, draft_len, logits,
+                                                 keys, t0, temp, tk, tp, bias)
             return out, n, lps, new_caches
 
         self._verify = jax.jit(verify_sample)
@@ -818,7 +826,7 @@ class Scheduler:
         ``drain_events``; without the lock an append racing the swap can
         land in the already-drained list and vanish."""
         with self._emit_lock:
-            self._events.append((rid, idx, tok, lp))
+            self._events.append((rid, idx, tok, lp, self._ready_t))
 
     def _mark_finished(self, rid: int) -> None:
         with self._emit_lock:
@@ -826,10 +834,12 @@ class Scheduler:
 
     def drain_events(self) -> list:
         """Return and clear the per-token events emitted since the last
-        call: ``(rid, token_index, token, logprob)`` tuples in emission
-        order — position order per request, interleaved across requests.
-        ``logprob`` is the token's log-probability under the row's raw
-        model distribution (``core.sampling.token_logprobs``).
+        call: ``(rid, token_index, token, logprob, host_t)`` tuples in
+        emission order — position order per request, interleaved across
+        requests. ``logprob`` is the token's log-probability under the
+        row's raw model distribution (``core.sampling.token_logprobs``);
+        ``host_t`` is the tracer-clock time the token reached the host
+        (None when the scheduler is untraced).
 
         SINGLE-CONSUMER: safe to call from a thread other than the one
         driving ``step()`` (the swap is atomic with event appends), but
@@ -926,15 +936,31 @@ class Scheduler:
                     self.params, i32(r, c), caches=caches,
                     positions=i32(r, c))}
 
-    def _register_shape(self, *shape) -> None:
+    def _register_shape(self, *shape, rows=(), fresh: int = 0) -> None:
         """Track every distinct jitted call shape the scheduler dispatches —
         ``stats.compiled_shapes`` is the compile-count the chunked mode
-        exists to bound."""
+        exists to bound. Called once per jitted model call. When traced,
+        the call's live pool tokens read go to the tick's counter: the
+        pool lengths of the ``rows`` it attends, less ``fresh`` of those
+        tokens that it attends from its own inputs instead."""
         new = shape not in self._shapes
         self._shapes.add(shape)
         self.stats.compiled_shapes = len(self._shapes)
         if self.telemetry is not None:
-            self.telemetry.shape_dispatch(new)
+            kv = sum(int(self.pool.lengths[i]) for i in rows) - fresh
+            self.telemetry.shape_dispatch(new, kv)
+
+    def _pull(self, phase: str, *arrays) -> tuple:
+        """Copy device results to the host: where a tick blocks on the
+        device, timed as the ``phase`` wait phase when traced. Its end is
+        when the tokens emitted from these results reached the host."""
+        tel = self.telemetry
+        if tel is None:
+            return tuple(np.asarray(a) for a in arrays)
+        with tel.phase(phase) as ph:
+            out = tuple(np.asarray(a) for a in arrays)
+        self._ready_t = ph.end
+        return out
 
     def _admission_target(self, req: Request) -> int:
         """TOKENS the admission must cover. Reserve mode: the request's
@@ -1045,45 +1071,49 @@ class Scheduler:
         column is every row's final prompt token → first sampled token.
         Forked rows carry only their SUFFIX (positions from prefix_len) and
         attend the shared pages through ``paged_prefill_shared``."""
-        toks = [self.slots[s].req.prefill_tokens for s in admitted]
-        starts = [int(self.pool.lengths[s]) for s in admitted]  # 0 or prefix
-        lens = [t.size - st for t, st in zip(toks, starts)]  # suffix lengths
-        s_pad = _bucket(max(lens))
-        r = len(admitted)
-        tokens = np.zeros((r, s_pad), np.int32)
-        posn = np.full((r, s_pad), -1, np.int32)
-        for i, slot in enumerate(admitted):
-            suffix = toks[i][starts[i]:]
-            tokens[i, s_pad - suffix.size:] = suffix
-            posn[i, s_pad - suffix.size:] = np.arange(starts[i], toks[i].size)
-        shared = any(st > 0 for st in starts)
-        fn = self._prefill_shared if shared else self._prefill
-        self._register_shape("prefill_shared" if shared else "prefill",
-                             r, s_pad)
         tel = self.telemetry
-        t0 = tel.now() if tel is not None else 0.0
-        logits, new_caches = fn(
-            self.params, jnp.asarray(tokens),
-            caches=self.pool.device_caches(rows=admitted),
-            positions=jnp.asarray(posn))
-        if tel is not None:
-            jax.block_until_ready(logits)  # honest phase timing; values
-            t1 = tel.now()                 # are untouched (bit-identity)
-        self.pool.update_from(new_caches)
-        first, first_lp = self._sample_first(logits, admitted)
-        for i, slot in enumerate(admitted):
-            st = self.slots[slot]
-            self.pool.commit_prefill(slot, int(toks[i].size))
-            st.prefilled = int(toks[i].size)
-            if tel is not None:
-                tel.add_span("prefill", t0, t1, track=f"slot{slot}",
-                             rid=st.req.rid, tokens=lens[i], stage="wave")
-            self._record_first_token(st, slot, int(first[i]),
-                                     float(first_lp[i]))
-            self._maybe_pin_prefix(st, slot)
-        self.stats.prefills += 1
-        self.stats.prefill_tokens += sum(lens)
-        self.stats.admitted += r
+        with maybe_phase(tel, "prefill.build"):
+            toks = [self.slots[s].req.prefill_tokens for s in admitted]
+            starts = [int(self.pool.lengths[s]) for s in admitted]  # 0/prefix
+            lens = [t.size - st for t, st in zip(toks, starts)]  # suffixes
+            s_pad = _bucket(max(lens))
+            r = len(admitted)
+            tokens = np.zeros((r, s_pad), np.int32)
+            posn = np.full((r, s_pad), -1, np.int32)
+            for i, slot in enumerate(admitted):
+                suffix = toks[i][starts[i]:]
+                tokens[i, s_pad - suffix.size:] = suffix
+                posn[i, s_pad - suffix.size:] = np.arange(starts[i],
+                                                          toks[i].size)
+            shared = any(st > 0 for st in starts)
+            fn = self._prefill_shared if shared else self._prefill
+        with maybe_phase(tel, "prefill.dispatch"):
+            self._register_shape("prefill_shared" if shared else "prefill",
+                                 r, s_pad, rows=admitted)
+            t0 = tel.now() if tel is not None else 0.0
+            logits, new_caches = fn(
+                self.params, jnp.asarray(tokens),
+                caches=self.pool.device_caches(rows=admitted),
+                positions=jnp.asarray(posn))
+            t1 = tel.now() if tel is not None else 0.0
+            self.pool.update_from(new_caches)
+            first, first_lp = self._sample_first(logits, admitted)
+        first, first_lp = self._pull("prefill.wait", first, first_lp)
+        with maybe_phase(tel, "emit"):
+            for i, slot in enumerate(admitted):
+                st = self.slots[slot]
+                self.pool.commit_prefill(slot, int(toks[i].size))
+                st.prefilled = int(toks[i].size)
+                if tel is not None:
+                    tel.add_span("prefill", t0, t1, track=f"slot{slot}",
+                                 rid=st.req.rid, tokens=lens[i],
+                                 stage="wave")
+                self._record_first_token(st, slot, int(first[i]),
+                                         float(first_lp[i]))
+                self._maybe_pin_prefix(st, slot)
+            self.stats.prefills += 1
+            self.stats.prefill_tokens += sum(lens)
+            self.stats.admitted += r
 
     def _sample_first(self, logits, rows: list | None) -> tuple:
         """Sample each row's FIRST token (generation index 0) from prefill
@@ -1094,7 +1124,8 @@ class Scheduler:
         gathers its rows' lanes from the same cached device operands —
         the per-slot arrays are never rebuilt host-side per call); rows
         that didn't finish their prompt this call simply discard the
-        sample. Returns (tokens, logprobs) as host arrays."""
+        sample. Returns (tokens, logprobs) as device arrays (the caller
+        pulls them with :meth:`_pull`)."""
         keys, temp, tk, tp, bias = self._device_ops()
         if rows is None:
             toks, lps = self._sample(logits, keys,
@@ -1104,7 +1135,7 @@ class Scheduler:
             toks, lps = self._sample_rows(
                 logits, jnp.asarray(np.asarray(rows, np.int32)), keys,
                 jnp.zeros((len(rows),), jnp.int32), temp, tk, tp, bias)
-        return np.asarray(toks), np.asarray(lps)
+        return toks, lps
 
     def _pick_chunk(self) -> int:
         """The tick's prefill chunk size. Fixed ladder of one → that size.
@@ -1153,58 +1184,63 @@ class Scheduler:
                 if st is not None and st.prefilling]
         if not rows:
             return False
-        c = self._pick_chunk()
-        fresh = [i for i in rows if int(self.pool.lengths[i]) == 0]
-        cont = [i for i in rows if int(self.pool.lengths[i]) > 0]
+        tel = self.telemetry
+        with maybe_phase(tel, "prefill.build"):
+            c = self._pick_chunk()
+            fresh = [i for i in rows if int(self.pool.lengths[i]) == 0]
+            cont = [i for i in rows if int(self.pool.lengths[i]) > 0]
         for group, fn, kind in ((fresh, self._prefill, "chunk"),
                                 (cont, self._prefill_shared, "chunk_shared")):
             if not group:
                 continue
-            tokens = np.zeros((self.max_slots, c), np.int32)
-            posn = np.full((self.max_slots, c), -1, np.int32)
-            ends = {}
-            for i in group:
-                st = self.slots[i]
-                toks = st.req.prefill_tokens
-                lo = st.prefilled
-                hi = min(lo + c, toks.size)
-                chunk = toks[lo:hi]
-                tokens[i, c - chunk.size:] = chunk
-                posn[i, c - chunk.size:] = np.arange(lo, hi)
-                ends[i] = (hi, toks.size)
-            self._register_shape(kind, self.max_slots, c)
-            tel = self.telemetry
-            t0 = tel.now() if tel is not None else 0.0
-            logits, new_caches = fn(
-                self.params, jnp.asarray(tokens),
-                caches=self.pool.device_caches(),
-                positions=jnp.asarray(posn))
-            if tel is not None:
-                jax.block_until_ready(logits)
-                t1 = tel.now()
-            self.pool.update_from(new_caches)
-            # only dispatch the sampler on ticks where some row actually
-            # completes its prompt — mid-prompt chunks discard the sample
-            first, first_lp = self._sample_first(logits, None) \
-                if any(hi == total for hi, total in ends.values()) \
-                else (None, None)
-            for i in group:
-                st = self.slots[i]
-                hi, total = ends[i]
-                self.pool.commit_prefill(i, hi)
-                chunk_tokens = hi - st.prefilled
-                st.prefilled = hi
-                self.stats.prefill_chunks += 1
-                self.stats.prefill_tokens += chunk_tokens
-                if tel is not None:
-                    tel.add_span("prefill", t0, t1, track=f"slot{i}",
-                                 rid=st.req.rid, tokens=chunk_tokens,
-                                 stage=kind, done=hi == total)
-                self._maybe_pin_prefix(st, i)
-                if hi == total:  # prompt complete → first token
-                    self._record_first_token(st, i, int(first[i]),
-                                             float(first_lp[i]))
-            self.stats.prefills += 1
+            with maybe_phase(tel, "prefill.build"):
+                tokens = np.zeros((self.max_slots, c), np.int32)
+                posn = np.full((self.max_slots, c), -1, np.int32)
+                ends = {}
+                for i in group:
+                    st = self.slots[i]
+                    toks = st.req.prefill_tokens
+                    lo = st.prefilled
+                    hi = min(lo + c, toks.size)
+                    chunk = toks[lo:hi]
+                    tokens[i, c - chunk.size:] = chunk
+                    posn[i, c - chunk.size:] = np.arange(lo, hi)
+                    ends[i] = (hi, toks.size)
+                # only sample on ticks where some row actually completes
+                # its prompt — mid-prompt chunks discard the sample
+                completes = any(hi == total for hi, total in ends.values())
+            with maybe_phase(tel, "prefill.dispatch"):
+                # a continuation chunk attends its pool history
+                self._register_shape(kind, self.max_slots, c, rows=group)
+                t0 = tel.now() if tel is not None else 0.0
+                logits, new_caches = fn(
+                    self.params, jnp.asarray(tokens),
+                    caches=self.pool.device_caches(),
+                    positions=jnp.asarray(posn))
+                t1 = tel.now() if tel is not None else 0.0
+                self.pool.update_from(new_caches)
+                if completes:
+                    first, first_lp = self._sample_first(logits, None)
+            if completes:
+                first, first_lp = self._pull("prefill.wait", first, first_lp)
+            with maybe_phase(tel, "emit"):
+                for i in group:
+                    st = self.slots[i]
+                    hi, total = ends[i]
+                    self.pool.commit_prefill(i, hi)
+                    chunk_tokens = hi - st.prefilled
+                    st.prefilled = hi
+                    self.stats.prefill_chunks += 1
+                    self.stats.prefill_tokens += chunk_tokens
+                    if tel is not None:
+                        tel.add_span("prefill", t0, t1, track=f"slot{i}",
+                                     rid=st.req.rid, tokens=chunk_tokens,
+                                     stage=kind, done=hi == total)
+                    self._maybe_pin_prefix(st, i)
+                    if hi == total:  # prompt complete → first token
+                        self._record_first_token(st, i, int(first[i]),
+                                                 float(first_lp[i]))
+                self.stats.prefills += 1
         return True
 
     def _release_idle_prefix(self) -> bool:
@@ -1376,46 +1412,52 @@ class Scheduler:
         rejection sampling. Inactive rows ride fully padded as ever."""
         k = self.speculate_k
         s = 1 + k
-        self._register_shape("verify", self.max_slots, s)
-        tokens = np.zeros((self.max_slots, s), np.int32)
-        posn = np.full((self.max_slots, s), -1, np.int32)
-        gather = np.zeros((self.max_slots, s), np.int32)
-        draft = np.zeros((self.max_slots, k), np.int32)
-        dlen = np.zeros((self.max_slots,), np.int32)
-        t0 = np.zeros((self.max_slots,), np.int32)
-        for i in active:
-            st = self.slots[i]
-            d = plan[i]
-            kd = d.size
-            # first position being written: the grow appended 1 + kd
-            base = int(self.pool.lengths[i]) - 1 - kd
-            tokens[i, s - 1 - kd:] = np.concatenate(
-                [[st.generated[-1]], d]).astype(np.int32)
-            posn[i, s - 1 - kd:] = np.arange(base, base + 1 + kd)
-            # verify column j (generation index t0 + j) lives at call
-            # column s - 1 - kd + j; clamp past the draft count (those
-            # gathers are garbage the sampler masks by draft_len)
-            gather[i] = s - 1 - kd + np.minimum(np.arange(s), kd)
-            draft[i, :kd] = d
-            dlen[i] = kd
-            t0[i] = len(st.generated)
-        keys, temp, tk, tp, bias = self._device_ops()
         tel = self.telemetry
-        if tel is not None:
+        with maybe_phase(tel, "decode.build"):
+            tokens = np.zeros((self.max_slots, s), np.int32)
+            posn = np.full((self.max_slots, s), -1, np.int32)
+            gather = np.zeros((self.max_slots, s), np.int32)
+            draft = np.zeros((self.max_slots, k), np.int32)
+            dlen = np.zeros((self.max_slots,), np.int32)
+            t0 = np.zeros((self.max_slots,), np.int32)
             for i in active:
-                tel.decode_begin(self.slots[i].req.rid, f"slot{i}")
-        out, n_acc, lps, new_caches = self._verify(
-            self.params, jnp.asarray(tokens),
-            caches=self.pool.device_caches(), positions=jnp.asarray(posn),
-            gather=jnp.asarray(gather), draft=jnp.asarray(draft),
-            draft_len=jnp.asarray(dlen), keys=keys, t0=jnp.asarray(t0),
-            temp=temp, tk=tk, tp=tp, bias=bias)
-        self.pool.update_from(new_caches)
-        out, n_acc, lps = np.asarray(out), np.asarray(n_acc), np.asarray(lps)
-        for i in active:
-            self._emit_burst(i, out[i], int(n_acc[i]), lps[i], plan[i].size)
-        self.stats.steps += 1
-        self.stats.slot_ticks += len(active)
+                st = self.slots[i]
+                d = plan[i]
+                kd = d.size
+                # first position being written: the grow appended 1 + kd
+                base = int(self.pool.lengths[i]) - 1 - kd
+                tokens[i, s - 1 - kd:] = np.concatenate(
+                    [[st.generated[-1]], d]).astype(np.int32)
+                posn[i, s - 1 - kd:] = np.arange(base, base + 1 + kd)
+                # verify column j (generation index t0 + j) lives at call
+                # column s - 1 - kd + j; clamp past the draft count (those
+                # gathers are garbage the sampler masks by draft_len)
+                gather[i] = s - 1 - kd + np.minimum(np.arange(s), kd)
+                draft[i, :kd] = d
+                dlen[i] = kd
+                t0[i] = len(st.generated)
+        with maybe_phase(tel, "decode.dispatch"):
+            # every key, the burst's too, is read back through the pool
+            self._register_shape("verify", self.max_slots, s, rows=active)
+            keys, temp, tk, tp, bias = self._device_ops()
+            if tel is not None:
+                for i in active:
+                    tel.decode_begin(self.slots[i].req.rid, f"slot{i}")
+            out, n_acc, lps, new_caches = self._verify(
+                self.params, jnp.asarray(tokens),
+                caches=self.pool.device_caches(),
+                positions=jnp.asarray(posn), gather=jnp.asarray(gather),
+                draft=jnp.asarray(draft), draft_len=jnp.asarray(dlen),
+                keys=keys, t0=jnp.asarray(t0), temp=temp, tk=tk, tp=tp,
+                bias=bias)
+            self.pool.update_from(new_caches)
+        out, n_acc, lps = self._pull("decode.wait", out, n_acc, lps)
+        with maybe_phase(tel, "emit"):
+            for i in active:
+                self._emit_burst(i, out[i], int(n_acc[i]), lps[i],
+                                 plan[i].size)
+            self.stats.steps += 1
+            self.stats.slot_ticks += len(active)
 
     def _decode_tick(self) -> None:
         """One ragged decode step over EVERY slot (single compiled shape);
@@ -1424,44 +1466,53 @@ class Scheduler:
         share the tick without sharing a shape. With ``speculate_k`` set
         the tick dispatches as one multi-token verify instead
         (:meth:`_verify_tick`)."""
-        plan = self._draft_plan()
-        self._grow_decode_slots(plan)
-        active = [i for i, st in enumerate(self.slots)
-                  if st is not None and not st.prefilling]
+        tel = self.telemetry
+        with maybe_phase(tel, "decode.build"):
+            plan = self._draft_plan()
+            self._grow_decode_slots(plan)
+            active = [i for i, st in enumerate(self.slots)
+                      if st is not None and not st.prefilling]
+            plain = bool(active) and not self.speculate_k
+            if plain:
+                tokens = np.zeros((self.max_slots, 1), np.int32)
+                pos = np.full((self.max_slots,), -1, np.int32)
+                # each row samples at its OWN generation index (folded
+                # into its PRNG lane) — the stream a request draws is
+                # independent of which slot it sits in and who else
+                # shares the batch
+                t = np.zeros((self.max_slots,), np.int32)
+                for i in active:
+                    tokens[i, 0] = self.slots[i].generated[-1]
+                    # the position being written
+                    pos[i] = int(self.pool.lengths[i]) - 1
+                    t[i] = len(self.slots[i].generated)
         if not active:
             return
         if self.speculate_k:
             self._verify_tick(active, plan)
             return
-        self._register_shape("decode", self.max_slots, 1)
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        pos = np.full((self.max_slots,), -1, np.int32)
-        # each row samples at its OWN generation index (folded into its
-        # PRNG lane) — the stream a request draws is independent of which
-        # slot it sits in and who else shares the batch
-        t = np.zeros((self.max_slots,), np.int32)
-        for i in active:
-            tokens[i, 0] = self.slots[i].generated[-1]
-            pos[i] = int(self.pool.lengths[i]) - 1  # position being written
-            t[i] = len(self.slots[i].generated)
-        keys, temp, tk, tp, bias = self._device_ops()
-        tel = self.telemetry
-        if tel is not None:
+        with maybe_phase(tel, "decode.dispatch"):
+            # each row attends its whole pool length, the new key included
+            self._register_shape("decode", self.max_slots, 1, rows=active)
+            keys, temp, tk, tp, bias = self._device_ops()
+            if tel is not None:
+                for i in active:
+                    tel.decode_begin(self.slots[i].req.rid, f"slot{i}")
+            nxt, lps, new_caches = self._decode(
+                self.params, jnp.asarray(tokens),
+                caches=self.pool.device_caches(), pos=jnp.asarray(pos),
+                keys=keys, t=jnp.asarray(t), temp=temp, tk=tk, tp=tp,
+                bias=bias)
+            self.pool.update_from(new_caches)
+        nxt, lps = self._pull("decode.wait", nxt, lps)
+        with maybe_phase(tel, "emit"):
             for i in active:
-                tel.decode_begin(self.slots[i].req.rid, f"slot{i}")
-        nxt, lps, new_caches = self._decode(
-            self.params, jnp.asarray(tokens),
-            caches=self.pool.device_caches(), pos=jnp.asarray(pos),
-            keys=keys, t=jnp.asarray(t), temp=temp, tk=tk, tp=tp, bias=bias)
-        self.pool.update_from(new_caches)
-        nxt, lps = np.asarray(nxt), np.asarray(lps)
-        for i in active:
-            st = self.slots[i]
-            st.generated.append(int(nxt[i]))
-            self._emit_event(st.req.rid, len(st.generated) - 1,
-                             int(nxt[i]), float(lps[i]))
-        self.stats.steps += 1
-        self.stats.slot_ticks += len(active)
+                st = self.slots[i]
+                st.generated.append(int(nxt[i]))
+                self._emit_event(st.req.rid, len(st.generated) - 1,
+                                 int(nxt[i]), float(lps[i]))
+            self.stats.steps += 1
+            self.stats.slot_ticks += len(active)
 
     def _packed_tick(self) -> bool:
         """ONE token-packed call for the whole tick: every decoding slot
@@ -1480,100 +1531,107 @@ class Scheduler:
         logits drift from the sequential decode path at quantization
         scale. Returns whether any work was dispatched."""
         k = self.speculate_k
-        if not k:
-            self._grow_decode_slots()
-        decode_rows = [] if k else [
-            i for i, st in enumerate(self.slots)
-            if st is not None and not st.prefilling]
-        t_budget = self.token_budget
-        tokens = np.zeros((1, t_budget), np.int32)
-        posn = np.full((1, t_budget), -1, np.int32)
-        slot_ids = np.full((1, t_budget), -1, np.int32)
-        # decode rows' fresh self-keys round-trip the int8 quantizer inside
-        # the packed step, so they attend exactly what a sequential decode
-        # step reads back from the pool; prefill rows keep f32 fresh keys
-        # (the same math as Engine's prompt prefill)
-        quant_fresh = np.zeros((1, t_budget), bool)
-        logit_rows = np.zeros((self.max_slots,), np.int32)
-        t_idx = np.zeros((self.max_slots,), np.int32)
-        # decode rows are never cut
-        budget = t_budget - len(decode_rows)
-        cap = self._pick_chunk() if any(
-            st is not None and st.prefilling for st in self.slots) else 0
-        cur = 0
-        pieces = {}  # slot → (lo, hi, total) prefill piece taken this tick
-        for i in range(self.max_slots):
-            st = self.slots[i]
-            if st is None:
-                continue
-            if not st.prefilling:
-                if k:
-                    continue  # speculating: decodes ride _verify_tick
-                tokens[0, cur] = st.generated[-1]
-                posn[0, cur] = int(self.pool.lengths[i]) - 1
-                slot_ids[0, cur] = i
-                quant_fresh[0, cur] = True
-                logit_rows[i] = cur
-                t_idx[i] = len(st.generated)
-                cur += 1
-            elif budget > 0:
-                toks = st.req.prefill_tokens
-                lo = st.prefilled
-                hi = min(lo + min(cap, budget), toks.size)
-                n = hi - lo
-                tokens[0, cur:cur + n] = toks[lo:hi]
-                posn[0, cur:cur + n] = np.arange(lo, hi)
-                slot_ids[0, cur:cur + n] = i
-                logit_rows[i] = cur + n - 1
-                pieces[i] = (lo, hi, toks.size)
-                budget -= n
-                cur += n
+        tel = self.telemetry
+        with maybe_phase(tel, "decode.build"):
+            if not k:
+                self._grow_decode_slots()
+            decode_rows = [] if k else [
+                i for i, st in enumerate(self.slots)
+                if st is not None and not st.prefilling]
+            t_budget = self.token_budget
+            tokens = np.zeros((1, t_budget), np.int32)
+            posn = np.full((1, t_budget), -1, np.int32)
+            slot_ids = np.full((1, t_budget), -1, np.int32)
+            # decode rows' fresh self-keys round-trip the int8 quantizer
+            # inside the packed step, so they attend exactly what a
+            # sequential decode step reads back from the pool; prefill rows
+            # keep f32 fresh keys (the same math as Engine's prompt prefill)
+            quant_fresh = np.zeros((1, t_budget), bool)
+            logit_rows = np.zeros((self.max_slots,), np.int32)
+            t_idx = np.zeros((self.max_slots,), np.int32)
+            # decode rows are never cut
+            budget = t_budget - len(decode_rows)
+            cap = self._pick_chunk() if any(
+                st is not None and st.prefilling for st in self.slots) else 0
+            cur = 0
+            pieces = {}  # slot → (lo, hi, total) prefill piece this tick
+            for i in range(self.max_slots):
+                st = self.slots[i]
+                if st is None:
+                    continue
+                if not st.prefilling:
+                    if k:
+                        continue  # speculating: decodes ride _verify_tick
+                    tokens[0, cur] = st.generated[-1]
+                    posn[0, cur] = int(self.pool.lengths[i]) - 1
+                    slot_ids[0, cur] = i
+                    quant_fresh[0, cur] = True
+                    logit_rows[i] = cur
+                    t_idx[i] = len(st.generated)
+                    cur += 1
+                elif budget > 0:
+                    toks = st.req.prefill_tokens
+                    lo = st.prefilled
+                    hi = min(lo + min(cap, budget), toks.size)
+                    n = hi - lo
+                    tokens[0, cur:cur + n] = toks[lo:hi]
+                    posn[0, cur:cur + n] = np.arange(lo, hi)
+                    slot_ids[0, cur:cur + n] = i
+                    logit_rows[i] = cur + n - 1
+                    pieces[i] = (lo, hi, toks.size)
+                    budget -= n
+                    cur += n
         if cur == 0:
             return False
-        self._register_shape("packed", self.max_slots, t_budget)
-        keys, temp, tk, tp, bias = self._device_ops()
-        tel = self.telemetry
-        if tel is not None:
-            for i in decode_rows:
-                tel.decode_begin(self.slots[i].req.rid, f"slot{i}")
-            t0 = tel.now()
-        nxt, lps, new_caches = self._packed(
-            self.params, jnp.asarray(tokens),
-            caches=self.pool.device_caches(), positions=jnp.asarray(posn),
-            slots=jnp.asarray(slot_ids), logit_rows=jnp.asarray(logit_rows),
-            quant_fresh=jnp.asarray(quant_fresh), keys=keys,
-            t=jnp.asarray(t_idx), temp=temp, tk=tk, tp=tp, bias=bias)
-        if tel is not None:
-            jax.block_until_ready(nxt)
-            t1 = tel.now()
-        self.pool.update_from(new_caches)
-        nxt, lps = np.asarray(nxt), np.asarray(lps)
-        for i, (lo, hi, total) in pieces.items():
-            st = self.slots[i]
-            self.pool.commit_prefill(i, hi)
-            st.prefilled = hi
-            self.stats.prefill_chunks += 1
-            self.stats.prefill_tokens += hi - lo
+        with maybe_phase(tel, "decode.dispatch"):
+            # a segment attends the pool below its first in-call position:
+            # a decode row's own new key comes from the buffer
+            self._register_shape("packed", self.max_slots, t_budget,
+                                 rows=decode_rows + list(pieces),
+                                 fresh=len(decode_rows))
+            keys, temp, tk, tp, bias = self._device_ops()
             if tel is not None:
-                tel.add_span("prefill", t0, t1, track=f"slot{i}",
-                             rid=st.req.rid, tokens=hi - lo, stage="packed",
-                             done=hi == total)
-            self._maybe_pin_prefix(st, i)
-            if hi == total:  # prompt complete → first token
-                self._record_first_token(st, i, int(nxt[i]), float(lps[i]))
-        for i in decode_rows:
-            st = self.slots[i]
-            st.generated.append(int(nxt[i]))
-            self._emit_event(st.req.rid, len(st.generated) - 1,
-                             int(nxt[i]), float(lps[i]))
-        self.stats.packed_ticks += 1
-        self.stats.packed_tokens += cur
-        self.stats.packed_pad_tokens += t_budget - cur
-        if pieces:
-            self.stats.prefills += 1
-        if decode_rows:
-            self.stats.steps += 1
-            self.stats.slot_ticks += len(decode_rows)
+                for i in decode_rows:
+                    tel.decode_begin(self.slots[i].req.rid, f"slot{i}")
+            t0 = tel.now() if tel is not None else 0.0
+            nxt, lps, new_caches = self._packed(
+                self.params, jnp.asarray(tokens),
+                caches=self.pool.device_caches(),
+                positions=jnp.asarray(posn), slots=jnp.asarray(slot_ids),
+                logit_rows=jnp.asarray(logit_rows),
+                quant_fresh=jnp.asarray(quant_fresh), keys=keys,
+                t=jnp.asarray(t_idx), temp=temp, tk=tk, tp=tp, bias=bias)
+            t1 = tel.now() if tel is not None else 0.0
+            self.pool.update_from(new_caches)
+        nxt, lps = self._pull("decode.wait", nxt, lps)
+        with maybe_phase(tel, "emit"):
+            for i, (lo, hi, total) in pieces.items():
+                st = self.slots[i]
+                self.pool.commit_prefill(i, hi)
+                st.prefilled = hi
+                self.stats.prefill_chunks += 1
+                self.stats.prefill_tokens += hi - lo
+                if tel is not None:
+                    tel.add_span("prefill", t0, t1, track=f"slot{i}",
+                                 rid=st.req.rid, tokens=hi - lo,
+                                 stage="packed", done=hi == total)
+                self._maybe_pin_prefix(st, i)
+                if hi == total:  # prompt complete → first token
+                    self._record_first_token(st, i, int(nxt[i]),
+                                             float(lps[i]))
+            for i in decode_rows:
+                st = self.slots[i]
+                st.generated.append(int(nxt[i]))
+                self._emit_event(st.req.rid, len(st.generated) - 1,
+                                 int(nxt[i]), float(lps[i]))
+            self.stats.packed_ticks += 1
+            self.stats.packed_tokens += cur
+            self.stats.packed_pad_tokens += t_budget - cur
+            if pieces:
+                self.stats.prefills += 1
+            if decode_rows:
+                self.stats.steps += 1
+                self.stats.slot_ticks += len(decode_rows)
         return True
 
     def _evict_finished(self) -> None:
@@ -1635,9 +1693,14 @@ class Scheduler:
 
         With ``telemetry=`` set, each tick additionally lands one
         :class:`~repro.serving.telemetry.TickRecord` (wall time, token/pad
-        counts, compile events, pool occupancy, queue depth); the
-        timeline is assembled from stat deltas, so the instrumented tick
-        runs the exact same scheduling decisions as the bare one.
+        counts, compile events, pool occupancy, queue depth, seconds
+        blocked on the device, model calls and pool tokens read) and its
+        host phases as ``host``-track spans that tile it (``admit``,
+        ``prefill.build``/``.dispatch``/``.wait``, ``decode.build``/
+        ``.dispatch``/``.wait``, ``emit``, ``evict``); the timeline is
+        assembled from stat deltas and host clocks, with no device sync
+        of its own, so the instrumented tick runs the exact same
+        scheduling decisions as the bare one.
 
         SINGLE-DRIVER: ticks mutate pool pages, block tables and slot
         state with no internal locking — one thread must own them
@@ -1690,17 +1753,23 @@ class Scheduler:
                                      for st in self.slots))
         return pending
 
+    def _evict(self) -> None:
+        """The tick's evict phase: occupancy peaks, then finished slots."""
+        with maybe_phase(self.telemetry, "evict"):
+            self._track_occupancy()
+            self._evict_finished()
+
     def _step_inner(self) -> bool:
         self._tick += 1
-        admitted, restored = self._admit_wave()
+        with maybe_phase(self.telemetry, "admit"):
+            admitted, restored = self._admit_wave()
         if restored:
             self.stats.admitted += len(restored)
         if self.tick_mode == "packed":
             self.stats.admitted += len(admitted)
             did = self._packed_tick()
             if did or restored:
-                self._track_occupancy()
-                self._evict_finished()
+                self._evict()
             if self.speculate_k:
                 # speculating: the packed call carried prefill only; the
                 # decode slots now ride the pool-only multi-token verify
@@ -1709,8 +1778,7 @@ class Scheduler:
                 if any(st is not None and not st.prefilling
                        for st in self.slots):
                     self._decode_tick()
-                    self._track_occupancy()
-                    self._evict_finished()
+                    self._evict()
                     return self.pending
             if not did and (not admitted and not restored and self.queue
                             and all(st is None for st in self.slots)):
@@ -1734,12 +1802,10 @@ class Scheduler:
             self.stats.admitted += len(admitted)
             did_prefill = self._prefill_chunk_tick()
         if did_prefill or restored:
-            self._track_occupancy()
-            self._evict_finished()  # max_new_tokens == 1 finishes here
+            self._evict()  # max_new_tokens == 1 finishes here
         if any(st is not None and not st.prefilling for st in self.slots):
             self._decode_tick()
-            self._track_occupancy()
-            self._evict_finished()
+            self._evict()
         elif (not admitted and not restored and self.queue
               and all(st is None for st in self.slots)):
             self._fail_stuck_queue()

@@ -13,7 +13,15 @@ through all three serving front ends:
     queue depth) and each request's lifecycle lands as spans:
     ``queued → prefill chunk(s) → first_token → decode →
     preempt/swap_out/swap_resume → finish``, each carrying its tick id
-    and reason;
+    and reason. Inside each tick the host's stretches are
+    :meth:`Tracer.phase` spans on a ``host`` track (``admit``,
+    ``prefill.build`` / ``.dispatch`` / ``.wait``, ``decode.build`` /
+    ``.dispatch`` / ``.wait``, ``emit``, ``evict``), which tile the tick;
+    the async engine adds ``engine.collect`` / ``engine.fanout`` /
+    ``engine.commands`` between ticks;
+  * ``serving.http.ServingHTTPServer`` — one ``http.request`` span per
+    completion request and one ``sse.write`` span per streamed frame,
+    from the token reaching the host to the socket drain;
   * ``serving.engine.Engine`` — one ``fused_generate`` span per jitted
     prefill+scan call with batch/token counters;
   * ``serving.split_engine.SplitEngine`` — per-segment ``edge`` /
@@ -44,6 +52,14 @@ Exporters:
 
 Clock: ``time.perf_counter`` (monotonic) by default — the same clock
 ``serving.api`` stamps ``RequestMetrics`` with — injectable for tests.
+While a ``jax.profiler`` trace is active, every host phase is also a
+``jax.profiler.TraceAnnotation``, so it sits beside the device's ops in
+the profile on the profiler's own clock (jax is looked up only if the
+process already imported it; this module stays stdlib-only).
+
+Retention is bounded: ``spans``, ``events`` and ``ticks`` keep the newest
+``MAX_RECORDS`` entries each, and the ``tracer.dropped`` counter says how
+many older ones a long-lived server let go.
 
 Percentiles are streaming via a DDSketch-style log-bucketed histogram
 (:class:`Histogram`): bounded relative error (default 1%), O(log range)
@@ -52,9 +68,13 @@ memory, no sample retention — fit for a long-lived server.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import json
 import math
+import sys
+import threading
 import time
 
 
@@ -206,9 +226,78 @@ class TickRecord:
     queue_depth: int
     active_slots: int
     prefilling_slots: int
+    wait_s: float = 0.0  # host seconds blocked on device results (*.wait)
+    kv_tokens_read: int = 0  # live pool tokens the calls' attention read
+
+    @property
+    def step_calls(self) -> int:
+        """Jitted model calls the tick dispatched (decode, each prefill
+        chunk or wave, verify, packed): every one registers its shape."""
+        return self.new_compiles + self.shape_hits
+
+
+def _profiler_note(name: str, tick: int | None):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` while a profiler
+    trace is active, else None. Never imports jax: a process that has not
+    imported it cannot be tracing."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    if tick is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, tick=tick)
+
+
+class _Phase:
+    """One host phase in progress; see :meth:`Tracer.phase`."""
+
+    __slots__ = ("tracer", "name", "start", "end", "_note")
+
+    def __init__(self, tracer: "Tracer", name: str):
+        self.tracer, self.name = tracer, name
+        self.start = self.end = None
+        self._note = None
+
+    def __enter__(self) -> "_Phase":
+        tel = self.tracer
+        self._note = _profiler_note(self.name, tel.current_tick)
+        if self._note is not None:
+            self._note.__enter__()
+        # inside a tick a phase starts where the tick's last phase (or
+        # the tick) ended, so the phases tile it
+        mark = tel._phase_mark
+        self.start = tel.now() if mark is None else mark
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tel = self.tracer
+        self.end = tel.now()
+        if tel._phase_mark is not None:
+            tel._phase_mark = self.end
+        if self._note is not None:
+            self._note.__exit__(*exc)
+            self._note = None
+        tel.add_span(self.name, self.start, self.end, track="host")
+        if self.name.endswith(".wait"):
+            tel._tick_wait += self.end - self.start
+
+
+def maybe_phase(tel, name: str):
+    """``tel.phase(name)``, or a context that does nothing when ``tel`` is
+    None — the guard every phase site uses, so ``telemetry=None`` calls
+    no Tracer method."""
+    return _NO_PHASE if tel is None else tel.phase(name)
+
+
+_NO_PHASE = contextlib.nullcontext()
 
 
 # ------------------------------------------------------------------ tracer
+
+_CURRENT = object()  # add_span's default: the tick in progress
+# records kept per list: a traced benchmark run (about 100 spans per
+# second over its set-up and window) stays far below it
+MAX_RECORDS = 200_000
 
 
 class Tracer:
@@ -221,14 +310,22 @@ class Tracer:
     instrumentation stays one guarded line per site; the generic
     ``span_begin`` / ``span_end`` / ``add_span`` / ``event`` API is
     available for everything else.
+
+    ``MAX_RECORDS`` bounds each of ``spans``, ``events`` and ``ticks``:
+    the oldest entry goes when a new one would pass it, counted under the
+    ``tracer.dropped`` counter.
     """
 
     def __init__(self, clock=time.perf_counter):
         self.clock = clock
         self.t0 = clock()
-        self.spans: list = []  # closed AND open spans, begin order
-        self.events: list = []  # (name, t, track, rid, attrs) instants
-        self.ticks: list = []
+        # closed AND open spans, begin order; appended from the tick thread
+        # and (add_span only) the HTTP front end's event-loop thread
+        self.spans = collections.deque(maxlen=MAX_RECORDS)
+        # (name, t, track, rid, attrs) instants
+        self.events = collections.deque(maxlen=MAX_RECORDS)
+        self.ticks = collections.deque(maxlen=MAX_RECORDS)
+        self._keep_lock = threading.Lock()
         self.metrics = MetricsRegistry()
         self.ttft_ticks: dict = {}  # rid -> ticks submit → first token
         self._open: dict = {}  # key -> Span
@@ -237,10 +334,20 @@ class Tracer:
         self._tick_open: tuple | None = None  # (tick, t_start, mode)
         self._tick_compiles = 0
         self._tick_hits = 0
+        self._tick_wait = 0.0
+        self._tick_kv = 0
+        self._phase_mark: float | None = None  # last phase boundary in a tick
         self.current_tick: int | None = None
 
     def now(self) -> float:
         return self.clock()
+
+    def _keep(self, records: collections.deque, item) -> None:
+        """Append to a bounded record list, counting what falls off."""
+        with self._keep_lock:
+            if len(records) == records.maxlen:
+                self.metrics.count("tracer.dropped")
+            records.append(item)
 
     # -------------------------------------------------------- generic API
 
@@ -254,7 +361,7 @@ class Tracer:
             attrs.setdefault("tick", self.current_tick)
         sp = Span(name, track, self.now(), rid=rid, attrs=attrs)
         self._open[key] = sp
-        self.spans.append(sp)
+        self._keep(self.spans, sp)
         return sp
 
     def span_end(self, key, **attrs) -> Span | None:
@@ -271,21 +378,38 @@ class Tracer:
         return sp
 
     def add_span(self, name: str, start: float, end: float, track: str,
-                 rid: int | None = None, **attrs) -> Span:
-        """Record an already-timed duration (caller holds t0/t1)."""
-        if self.current_tick is not None:
-            attrs.setdefault("tick", self.current_tick)
+                 rid: int | None = None, tick=_CURRENT, **attrs) -> Span:
+        """Record an already-timed duration (caller holds t0/t1). The span
+        carries the tick in progress unless ``tick`` is given; a caller on
+        another thread than the tick thread passes it (None: no tick)."""
+        if tick is _CURRENT:
+            tick = self.current_tick
+        if tick is not None:
+            attrs.setdefault("tick", tick)
         sp = Span(name, track, start, end, rid=rid, attrs=attrs)
-        self.spans.append(sp)
+        self._keep(self.spans, sp)
         return sp
+
+    def phase(self, name: str) -> _Phase:
+        """Context manager timing one host phase: a span named ``name`` on
+        the ``host`` track carrying the current tick, mirrored as a
+        ``jax.profiler.TraceAnnotation`` while a profiler trace is active.
+        Inside a tick the phases tile it: each starts where the previous
+        one (or the tick) ended, so host time between two phase sites,
+        the OS running another thread included, counts to the phase that
+        follows; only the stretch after the tick's last phase is left.
+        Phases named ``*.wait`` (the host blocked on device results) sum
+        into the tick's ``TickRecord.wait_s``; the phase's ``end`` is the
+        time those results reached the host."""
+        return _Phase(self, name)
 
     def event(self, name: str, track: str = "ticks", rid: int | None = None,
               t: float | None = None, **attrs) -> None:
         """Record an instant event (Chrome ``ph: "i"``)."""
         if self.current_tick is not None:
             attrs.setdefault("tick", self.current_tick)
-        self.events.append((name, self.now() if t is None else t, track,
-                            rid, attrs))
+        self._keep(self.events, (name, self.now() if t is None else t,
+                                 track, rid, attrs))
 
     # -------------------------------------------------- request lifecycle
 
@@ -344,13 +468,18 @@ class Tracer:
 
     def tick_begin(self, tick: int, mode: str) -> None:
         self._tick_open = (int(tick), self.now(), mode)
+        self._phase_mark = self._tick_open[1]
         self.current_tick = int(tick)
         self._tick_compiles = 0
         self._tick_hits = 0
+        self._tick_wait = 0.0
+        self._tick_kv = 0
 
-    def shape_dispatch(self, new: bool) -> None:
-        """One jitted dispatch this tick; ``new`` = first time this call
-        shape was seen (an XLA compile)."""
+    def shape_dispatch(self, new: bool, kv_tokens: int = 0) -> None:
+        """One jitted model call this tick; ``new`` = first time this call
+        shape was seen (an XLA compile); ``kv_tokens`` = live pool tokens
+        its attention reads, summed over its rows."""
+        self._tick_kv += int(kv_tokens)
         if new:
             self._tick_compiles += 1
             self.metrics.count("compile.shapes")
@@ -369,6 +498,7 @@ class Tracer:
             return
         tick, t_start, mode = self._tick_open
         self._tick_open = None
+        self._phase_mark = None
         self.current_tick = None
         wall = self.now() - t_start
         rec = TickRecord(tick, t_start, wall, mode, int(tokens),
@@ -376,8 +506,9 @@ class Tracer:
                          self._tick_compiles, self._tick_hits,
                          int(pages_in_use), int(pages_shared),
                          int(swap_bytes), int(queue_depth),
-                         int(active_slots), int(prefilling_slots))
-        self.ticks.append(rec)
+                         int(active_slots), int(prefilling_slots),
+                         self._tick_wait, self._tick_kv)
+        self._keep(self.ticks, rec)
         m = self.metrics
         m.observe("tick.wall_s", wall)
         m.count("tick.count")
@@ -388,8 +519,6 @@ class Tracer:
         m.gauge("pool.pages_shared", rec.pages_shared)
         m.gauge("pool.swap_bytes", rec.swap_bytes)
         m.gauge("queue.depth", rec.queue_depth)
-        m.observe("queue.depth_per_tick", rec.queue_depth)
-        m.observe("pool.pages_in_use_per_tick", rec.pages_in_use)
 
     # ----------------------------------------------------------- exporters
 
@@ -423,9 +552,14 @@ class Tracer:
                     order[track] = 1000 + len(order)
             return order[track]
 
+        # copy under the append lock: the tick thread and the front end's
+        # event-loop thread keep appending while a live server exports
+        with self._keep_lock:
+            spans, instants, ticks = (list(self.spans), list(self.events),
+                                      list(self.ticks))
         now = self.now()
         events: list = []
-        for sp in self.spans:
+        for sp in spans:
             end = now if sp.end is None else sp.end
             args = dict(sp.attrs)
             if sp.rid is not None:
@@ -437,16 +571,17 @@ class Tracer:
                 "tid": tid(sp.track), "ts": self._us(sp.start),
                 "dur": max(0.0, self._us(end) - self._us(sp.start)),
                 "args": args})
-        for name, t, track, rid, attrs in self.events:
+        for name, t, track, rid, attrs in instants:
             args = dict(attrs)
             if rid is not None:
                 args["rid"] = rid
             events.append({"name": name, "ph": "i", "cat": "instant",
                            "pid": 0, "tid": tid(track),
                            "ts": self._us(t), "s": "t", "args": args})
-        for rec in self.ticks:
+        for rec in ticks:
             args = dataclasses.asdict(rec)
             del args["start"], args["wall_s"]
+            args["step_calls"] = rec.step_calls
             events.append({
                 "name": f"tick[{rec.mode}]", "ph": "X", "cat": "tick",
                 "pid": 0, "tid": tid("ticks"), "ts": self._us(rec.start),

@@ -114,7 +114,7 @@ def _check_schedule(sched, oracle, jobs):
     events = sched.drain_events()
     problems = []
     seen = {}
-    for rid, idx, tok, lp in events:
+    for rid, idx, tok, lp, _ in events:
         if idx != seen.get(rid, -1) + 1:
             problems.append(f"rid {rid}: event index {idx} after "
                             f"{seen.get(rid, -1)}")

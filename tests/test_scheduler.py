@@ -318,7 +318,7 @@ def test_speculative_scheduler_matches_engine(tiny_model, mode):
     assert 0.0 < st.acceptance_rate <= 1.0
     # multi-token emission: indices strictly ordered with finite logprobs
     seen = {}
-    for rid, idx, tok, lp in s.drain_events():
+    for rid, idx, tok, lp, _ in s.drain_events():
         assert idx == seen.get(rid, -1) + 1 and np.isfinite(lp)
         seen[rid] = idx
 

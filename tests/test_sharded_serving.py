@@ -108,7 +108,7 @@ def _drive(sched, jobs):
 def _assert_streams_match(sched, rids, jobs, oracle):
     events = sched.drain_events()
     seen = {}
-    for rid, idx, tok, lp in events:
+    for rid, idx, tok, lp, _ in events:
         assert idx == seen.get(rid, -1) + 1, f"rid {rid} events out of order"
         seen[rid] = idx
         assert np.isfinite(lp)

@@ -1,10 +1,13 @@
 """Unified serving telemetry (``serving.telemetry``): streaming-histogram
 percentile correctness, full request-lifecycle span coverage on a
-preemption workload, the disabled-path no-op guarantee, Chrome-trace
-schema validation (incl. ``tools/trace_report.py``), greedy bit-identity
-with tracing on vs. off, ``LLMServer.metrics()``, and split-engine wire
-accounting."""
+preemption workload, host phases that tile each tick, the per-tick work
+counters, the HTTP/SSE front end's spans, bounded retention, the
+disabled-path no-op guarantee, Chrome-trace schema validation (incl.
+``tools/trace_report.py``), greedy bit-identity with tracing on vs. off
+and no device sync of the tracer's own, ``LLMServer.metrics()``, and
+split-engine wire accounting."""
 
+import asyncio
 import json
 
 import jax
@@ -17,6 +20,8 @@ from repro.core.sampling import SamplingParams
 from repro.models.transformer import RuntimeOpts, init_params
 from repro.serving import (Engine, Histogram, LLMServer, Scheduler,
                            SplitEngine, Tracer)
+from repro.serving.async_engine import AsyncLLMServer
+from repro.serving.http import ServingHTTPServer, SSEParser
 
 OPTS_Q = RuntimeOpts(q_chunk=16, kv_chunk=16, remat=False, quantized_kv=True,
                      moe_capacity_factor=0.0)
@@ -185,17 +190,30 @@ def test_disabled_path_never_touches_tracer(tiny_model, monkeypatch):
     assert se.telemetry is None
     se.generate(np.arange(5, dtype=np.int32)[None] % cfg.vocab_size, 3,
                 compress=False)
+    # the served path: HTTP/SSE front end -> async engine -> paged backend
+    srv = LLMServer(cfg, params, OPTS_Q, backend="paged", num_pages=24,
+                    page_size=4, max_slots=2)
+    assert srv.tracer is None
+    outs = _serve_http(srv, [[1, 2, 3, 4, 5], [6, 7, 8]], max_tokens=3)
+    assert all(len(_frames(msgs)) == 4 for msgs in outs)  # 3 + finish
 
 
 # ------------------------------------------------------- greedy identity
 
 
-def test_greedy_bit_identical_telemetry_on_vs_off(tiny_model):
+def test_greedy_bit_identical_telemetry_on_vs_off(tiny_model, monkeypatch):
     """Acceptance: tracing must observe, never perturb — the preemption
-    workload's greedy tokens are IDENTICAL with telemetry on and off."""
+    workload's greedy tokens are IDENTICAL with telemetry on and off, and
+    the traced run makes no device sync of its own (no
+    ``block_until_ready``): it schedules as the untraced one does."""
     cfg, params = tiny_model
     _, rids_off, _, _, res_off = _preemption_run(cfg, params, None)
+    syncs = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: syncs.append(1) or real(x))
     _, rids_on, _, _, res_on = _preemption_run(cfg, params, Tracer())
+    assert syncs == []
     for ra, rb in zip(rids_off, rids_on):
         np.testing.assert_array_equal(res_off[ra], res_on[rb])
 
@@ -346,6 +364,235 @@ def test_split_backend_telemetry_wire_accounting(tiny_model):
     assert m["split.tabq_bits.count"] > 0
     assert 1 <= m["split.tabq_bits.min"] <= m["split.tabq_bits.max"] <= 16
     assert m["split.edge_s.count"] >= 1 and m["split.cloud_s.count"] >= 1
+
+
+# ------------------------------------------ host phases and work counters
+
+HOST_PHASES = {"admit", "prefill.build", "prefill.dispatch", "prefill.wait",
+               "decode.build", "decode.dispatch", "decode.wait", "emit",
+               "evict"}
+
+
+def _chunked_run(cfg, params, tracer, tick_mode="chunked", wrap=None):
+    """Ragged prompts longer than the 8-token chunk, so ticks mix fresh
+    chunks, continuation chunks and decode. ``wrap(sched)`` may swap the
+    scheduler's jitted calls for spies before anything is submitted.
+    Returns the drained scheduler and {rid: prompt length}."""
+    rng = np.random.default_rng(21)
+    sched = Scheduler(cfg, params, OPTS_Q, num_pages=48, page_size=4,
+                      max_slots=3, prefill_chunk=8, tick_mode=tick_mode,
+                      telemetry=tracer)
+    if wrap is not None:
+        wrap(sched)
+    prompts = {}
+    for n, new in ((5, 6), (19, 4), (11, 7), (3, 5), (14, 3)):
+        p = rng.integers(0, cfg.vocab_size, (n,))
+        prompts[sched.submit(p, new)] = n
+    sched.run()
+    return sched, prompts
+
+
+def test_host_phases_tile_chunked_ticks(tiny_model):
+    """The host phases of a chunked run tile each tick: the first starts
+    at the tick's start, each next one where the last ended, and together
+    they cover >= 95% of its wall time. They carry their tick, and the
+    tick's wait_s is the sum of its ``*.wait`` phases."""
+    cfg, params = tiny_model
+    tracer = Tracer()
+    _chunked_run(cfg, params, tracer)
+    phases = [sp for sp in tracer.spans if sp.track == "host"]
+    assert {sp.name for sp in phases} == HOST_PHASES
+    by_tick: dict = {}
+    for sp in phases:
+        by_tick.setdefault(sp.attrs["tick"], []).append(sp)
+    assert len(tracer.ticks) > 5
+    for rec in tracer.ticks:
+        mine = sorted(by_tick[rec.tick], key=lambda sp: sp.start)
+        assert all(a.end == b.start for a, b in zip(mine, mine[1:]))
+        assert mine[0].start == rec.start
+        assert mine[-1].end <= rec.start + rec.wall_s
+        covered = sum(sp.duration for sp in mine)
+        assert covered >= 0.95 * rec.wall_s, (rec.tick, covered, rec.wall_s)
+        assert rec.wait_s == pytest.approx(sum(
+            sp.duration for sp in mine if sp.name.endswith(".wait")))
+
+
+@pytest.mark.parametrize("tick_mode", ["chunked", "packed"])
+def test_kv_tokens_read_matches_pool_lengths_and_work_rebuild(tiny_model,
+                                                              tick_mode):
+    """kv_tokens_read is counted where the calls are dispatched: on every
+    chunked decode tick it equals the decoding slots' pool lengths (read
+    here off the decode call's own position operand), and on every tick
+    it equals what the benchmark's ``bench/work.py`` rebuilds from the
+    spans — decode contexts plus each continuation chunk's pool history;
+    a packed decode row attends its own new key from the buffer."""
+    from bench import work
+
+    cfg, params = tiny_model
+    tracer = Tracer()
+    decode_kv = {}  # tick -> pool tokens the decode call's rows attend
+
+    def observe(sched):
+        real = sched._decode
+
+        def spy(*a, pos, **k):
+            p = np.asarray(pos)
+            decode_kv[sched._tick] = int((p[p >= 0] + 1).sum())
+            return real(*a, pos=pos, **k)
+
+        sched._decode = spy
+
+    _, prompts = _chunked_run(cfg, params, tracer, tick_mode=tick_mode,
+                              wrap=observe)
+    chunks = work.prefill_chunks(tracer.spans)
+    ranges = work.decode_ranges(tracer.spans, prompts)
+    assert any(hist for c in chunks.values() for _, hist, _, _ in c)
+    own = 1 if tick_mode == "packed" else 0
+    for rec in tracer.ticks:
+        if tick_mode == "chunked" and rec.tick not in chunks:
+            assert rec.kv_tokens_read == decode_kv[rec.tick], rec.tick
+        ctx = work.contexts_at(ranges, rec.tick)
+        want = sum(ctx) - own * len(ctx) + sum(
+            hist for _, hist, _, _ in chunks.get(rec.tick, ()))
+        assert rec.kv_tokens_read == want, rec.tick
+
+
+@pytest.mark.parametrize("tick_mode", ["chunked", "packed", "wave"])
+def test_step_calls_count_the_dispatches(tiny_model, tick_mode):
+    """TickRecord.step_calls counts every jitted model call a tick
+    dispatches: each prefill chunk or wave, decode, packed call."""
+    cfg, params = tiny_model
+    tracer, calls = Tracer(), []
+
+    def count(sched):
+        for name in ("_prefill", "_prefill_shared", "_decode", "_packed"):
+            fn = getattr(sched, name)
+            setattr(sched, name, lambda *a, _fn=fn, **k:
+                    calls.append(1) or _fn(*a, **k))
+
+    _chunked_run(cfg, params, tracer, tick_mode=tick_mode, wrap=count)
+    assert sum(r.step_calls for r in tracer.ticks) == len(calls) > 0
+    if tick_mode != "packed":  # a chunk and a decode share some tick
+        assert max(r.step_calls for r in tracer.ticks) >= 2
+
+
+# ------------------------------------------------ HTTP/SSE front end spans
+
+
+def _serve_http(server, prompts, max_tokens):
+    """Stream each prompt through ServingHTTPServer -> AsyncLLMServer ->
+    ``server`` concurrently; returns each stream's parsed SSE payloads."""
+
+    async def one(host, port, prompt):
+        reader, writer = await asyncio.open_connection(host, port)
+        body = json.dumps({"prompt": prompt, "max_tokens": max_tokens,
+                           "stream": True}).encode()
+        writer.write(f"POST /v1/completions HTTP/1.1\r\nContent-Length: "
+                     f"{len(body)}\r\n\r\n".encode() + body)
+        await writer.drain()
+        raw = await reader.read()  # Connection: close — EOF-terminated
+        writer.close()
+        return SSEParser().feed(raw.split(b"\r\n\r\n", 1)[1])
+
+    async def go():
+        http = ServingHTTPServer(AsyncLLMServer(server))
+        await http.start()
+        try:
+            return await asyncio.gather(
+                *[one(http.host, http.port, p) for p in prompts])
+        finally:
+            await http.stop()
+
+    return asyncio.run(asyncio.wait_for(go(), 120.0))
+
+
+def _frames(msgs):
+    return [m for m in msgs if m != "[DONE]"]
+
+
+def test_http_records_one_span_per_request_and_frame(tiny_model):
+    """A traced server records one ``http.request`` span per completion
+    request and one ``sse.write`` span per streamed frame, on the
+    request's own track; a token frame's span starts when its token
+    reached the host — the end of the wait phase that pulled it."""
+    cfg, params = tiny_model
+    srv = LLMServer(cfg, params, OPTS_Q, backend="paged", num_pages=24,
+                    page_size=4, max_slots=2, telemetry=True)
+    outs = _serve_http(srv, [[1, 2, 3, 4, 5], [6, 7, 8], [9, 10]],
+                       max_tokens=4)
+    spans = list(srv.tracer.spans)
+    reqs = [sp for sp in spans if sp.name == "http.request"]
+    writes = [sp for sp in spans if sp.name == "sse.write"]
+    assert len(reqs) == 3
+    rids = {m["rid"] for msgs in outs for m in _frames(msgs)}
+    assert {sp.rid for sp in reqs} == rids
+    for sp in reqs:
+        assert sp.track == f"http:{sp.rid}" and "tick" not in sp.attrs
+        assert sp.end >= sp.start
+    for msgs in outs:
+        rid = _frames(msgs)[0]["rid"]
+        mine = [sp for sp in writes if sp.rid == rid]
+        assert len(mine) == len(_frames(msgs)) == 5  # 4 tokens + finish
+        assert {sp.track for sp in mine} == {f"http:{rid}"}
+        assert all(sp.end >= sp.start for sp in mine)
+    wait_ends = {sp.end for sp in spans if sp.name.endswith(".wait")}
+    tokens = [sp for sp in writes if sp.attrs["index"] < 4]
+    assert all(sp.start in wait_ends for sp in tokens)
+    # the engine thread's work between ticks is phased too
+    names = {sp.name for sp in spans if sp.track == "host"}
+    assert {"engine.collect", "engine.fanout", "engine.commands"} <= names
+
+
+# ------------------------------------------------------ bounded retention
+
+
+def test_tracer_keeps_the_newest_records_and_counts_drops(monkeypatch):
+    """spans, events and ticks each keep the newest ``MAX_RECORDS``; what
+    falls off is counted under ``tracer.dropped``."""
+    from repro.serving import telemetry
+
+    monkeypatch.setattr(telemetry, "MAX_RECORDS", 4)
+    tracer = Tracer()
+    for i in range(7):
+        tracer.add_span("s", float(i), float(i) + 0.5, track="t", n=i)
+        tracer.event("e", n=i)
+        tracer.tick_begin(i, "chunked")
+        tracer.tick_end()
+    assert [sp.attrs["n"] for sp in tracer.spans] == [3, 4, 5, 6]
+    assert [e[4]["n"] for e in tracer.events] == [3, 4, 5, 6]
+    assert [r.tick for r in tracer.ticks] == [3, 4, 5, 6]
+    assert tracer.metrics_dict()["tracer.dropped"] == 9
+
+
+def test_export_while_another_thread_appends():
+    """A live server exports while its tick and event-loop threads keep
+    recording: the export reads a snapshot and never sees a list change
+    under it."""
+    import threading
+
+    tracer = Tracer()
+    for i in range(20_000):
+        tracer.add_span("s", float(i), float(i) + 0.5, track="t")
+    stop = threading.Event()
+
+    def append():
+        i = 0
+        while not stop.is_set():
+            tracer.add_span("sse.write", 0.0, 1.0, track="req0", tick=None)
+            tracer.event("e", n=i)
+            tracer.tick_begin(i, "chunked")
+            tracer.tick_end()
+            i += 1
+
+    writer = threading.Thread(target=append)
+    writer.start()
+    try:
+        for _ in range(5):
+            trace = tracer.export_chrome_trace()
+            assert len(trace["traceEvents"]) > 20_000
+    finally:
+        stop.set()
+        writer.join()
 
 
 # ------------------------------------------------------- kv pool gauges
