@@ -10,9 +10,10 @@ the serving path, not a side gallery:
       makes both serving engines take this path inside their fused loops.
   paged_decode_attention — the same online-softmax block walk re-addressed
       through per-request BLOCK TABLES (``pltpu.PrefetchScalarGridSpec``):
-      each (request, kv-head) program gathers its pages from the shared
-      ``serving.kv_pool`` pool, with per-request causal bounds for ragged
-      continuous batching. ``models.layers.paged_decode_attention_layer``
+      each request's walk gathers its pages, all kv heads and 128 tokens a
+      step, from the shared ``serving.kv_pool`` pool, up to the page of its
+      query position and no further, with per-request causal bounds for
+      ragged continuous batching. ``models.layers.paged_decode_attention_layer``
       routes every decode over a ``PagedKVCache`` here.
   paged_prefill_attention — the PREFILL page walk (the TTFT path): a
       flash-style (request, kv-head, q-block) grid folds the request's

@@ -123,6 +123,7 @@ from repro.configs.base import ArchConfig
 from repro.core.sampling import (SamplingParams, bias_rows,
                                  sample_tokens_with_logprobs,
                                  speculative_verify, truncate_at_stop)
+from repro.kernels.paged_decode_attention import live_pages
 from repro.models.transformer import (RuntimeOpts, packed_step,
                                       paged_decode_step, paged_prefill,
                                       paged_prefill_shared, paged_verify_step,
@@ -936,19 +937,28 @@ class Scheduler:
                     self.params, i32(r, c), caches=caches,
                     positions=i32(r, c))}
 
-    def _register_shape(self, *shape, rows=(), fresh: int = 0) -> None:
+    def _register_shape(self, *shape, rows=(), fresh: int = 0,
+                        walk: bool = False) -> None:
         """Track every distinct jitted call shape the scheduler dispatches —
         ``stats.compiled_shapes`` is the compile-count the chunked mode
         exists to bound. Called once per jitted model call. When traced,
         the call's live pool tokens read go to the tick's counter: the
         pool lengths of the ``rows`` it attends, less ``fresh`` of those
-        tokens that it attends from its own inputs instead."""
+        tokens that it attends from its own inputs instead. A ``walk``
+        call (single-token decode, the paged decode kernel's walk) also
+        counts the block-table entries its rows walk: each row's
+        ``live_pages`` of its query position, the last pool token."""
         new = shape not in self._shapes
         self._shapes.add(shape)
         self.stats.compiled_shapes = len(self._shapes)
         if self.telemetry is not None:
             kv = sum(int(self.pool.lengths[i]) for i in rows) - fresh
-            self.telemetry.shape_dispatch(new, kv)
+            walked = 0
+            if walk and rows:
+                walked = int(live_pages(
+                    self.pool.lengths[list(rows)] - 1, self.pool.page_size,
+                    self.pool.max_blocks).sum())
+            self.telemetry.shape_dispatch(new, kv, walked)
 
     def _pull(self, phase: str, *arrays) -> tuple:
         """Copy device results to the host: where a tick blocks on the
@@ -1493,7 +1503,8 @@ class Scheduler:
             return
         with maybe_phase(tel, "decode.dispatch"):
             # each row attends its whole pool length, the new key included
-            self._register_shape("decode", self.max_slots, 1, rows=active)
+            self._register_shape("decode", self.max_slots, 1, rows=active,
+                                 walk=True)
             keys, temp, tk, tp, bias = self._device_ops()
             if tel is not None:
                 for i in active:

@@ -228,6 +228,7 @@ class TickRecord:
     prefilling_slots: int
     wait_s: float = 0.0  # host seconds blocked on device results (*.wait)
     kv_tokens_read: int = 0  # live pool tokens the calls' attention read
+    kv_pages_walked: int = 0  # block-table entries the decode walks covered
 
     @property
     def step_calls(self) -> int:
@@ -336,6 +337,7 @@ class Tracer:
         self._tick_hits = 0
         self._tick_wait = 0.0
         self._tick_kv = 0
+        self._tick_pages = 0
         self._phase_mark: float | None = None  # last phase boundary in a tick
         self.current_tick: int | None = None
 
@@ -474,12 +476,17 @@ class Tracer:
         self._tick_hits = 0
         self._tick_wait = 0.0
         self._tick_kv = 0
+        self._tick_pages = 0
 
-    def shape_dispatch(self, new: bool, kv_tokens: int = 0) -> None:
+    def shape_dispatch(self, new: bool, kv_tokens: int = 0,
+                       kv_pages: int = 0) -> None:
         """One jitted model call this tick; ``new`` = first time this call
         shape was seen (an XLA compile); ``kv_tokens`` = live pool tokens
-        its attention reads, summed over its rows."""
+        its attention reads, summed over its rows; ``kv_pages`` =
+        block-table entries its paged decode walk covers, summed over its
+        rows."""
         self._tick_kv += int(kv_tokens)
+        self._tick_pages += int(kv_pages)
         if new:
             self._tick_compiles += 1
             self.metrics.count("compile.shapes")
@@ -507,7 +514,7 @@ class Tracer:
                          int(pages_in_use), int(pages_shared),
                          int(swap_bytes), int(queue_depth),
                          int(active_slots), int(prefilling_slots),
-                         self._tick_wait, self._tick_kv)
+                         self._tick_wait, self._tick_kv, self._tick_pages)
         self._keep(self.ticks, rec)
         m = self.metrics
         m.observe("tick.wall_s", wall)

@@ -11,6 +11,7 @@ import pytest
 from repro.configs import get_config
 from repro.kernels import ref
 from repro.kernels.ops import decode_attention, paged_decode_attention
+from repro.kernels.paged_decode_attention import live_pages, pages_per_step
 from repro.models.transformer import (RuntimeOpts, decode_step, init_params,
                                       paged_decode_step, paged_prefill,
                                       prefill)
@@ -20,14 +21,16 @@ OPTS_Q = RuntimeOpts(q_chunk=16, kv_chunk=16, remat=False, quantized_kv=True,
                      moe_capacity_factor=0.0)
 
 
-def _pool_fixture(rng, p=10, kh=2, page=16, hd=32, lens=(40, 20, 10)):
+def _pool_fixture(rng, p=10, kh=2, page=16, hd=32, lens=(40, 20, 10),
+                  maxb=None):
     """A hand-built pool: request r holds ``lens[r]`` tokens in pages
-    [1 + sum(prior pages)...]; page 0 is trash (pos = -1)."""
+    [1 + sum(prior pages)...]; page 0 is trash (pos = -1). The block
+    table is ``maxb`` entries wide (default: the longest request's)."""
     kc = jnp.asarray(rng.integers(-127, 128, (p, kh, page, hd)), jnp.int8)
     vc = jnp.asarray(rng.integers(-127, 128, (p, kh, page, hd)), jnp.int8)
     ks = jnp.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), jnp.float32)
     vs = jnp.asarray(rng.uniform(0.005, 0.02, (p, kh, 1, page)), jnp.float32)
-    maxb = max(-(-n // page) for n in lens)
+    maxb = maxb or max(-(-n // page) for n in lens)
     bt = np.zeros((len(lens), maxb), np.int32)
     pool_pos = np.full((p, 1, page), -1, np.int32)
     nxt = 1
@@ -104,6 +107,79 @@ def test_paged_kernel_inactive_row_is_finite_zero():
                                  jnp.asarray([39, -1], jnp.int32))
     assert bool(jnp.all(jnp.isfinite(out)))
     np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
+
+
+# The walk's bounds. Every case poisons the block-table entries past each
+# row's last live page (q_pos // page + 1 entries live) with another row's
+# first page, whose positions 0..15 include keys <= q_pos: a walk that
+# went past the live entries would attend them. The kernel must equal the
+# oracle on the clean table, where those entries hold the trash page.
+WALK_CASES = {
+    # q_pos on both sides of page boundaries (page = 16)
+    "page_boundaries": dict(lens=(16, 17, 32, 33), maxb=4),
+    # a table not a multiple of the 16 pages a step takes: two steps, the
+    # second 5 entries long
+    "odd_max_blocks": dict(lens=(40, 20, 300, 10), maxb=21),
+    # the agent cell's widths (8 kv heads of 128, 4 queries each), rows
+    # of unequal length and one free row
+    "agent_shapes": dict(lens=(300, 37, 129, 0, 64, 1), maxb=21, kh=8, g=4,
+                         hd=128),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_paged_kernel_walk_stops_at_last_live_page(case):
+    c = dict(kh=2, g=2, hd=32) | WALK_CASES[case]
+    lens, page = c["lens"], 16
+    rng = np.random.default_rng(len(case))
+    kc, ks, vc, vs, pool_pos, bt = _pool_fixture(
+        rng, p=2 + sum(-(-n // page) for n in lens), kh=c["kh"], hd=c["hd"],
+        lens=lens, maxb=c["maxb"])
+    q_pos = np.asarray([n - 1 for n in lens], np.int32)
+    live = live_pages(q_pos, page, c["maxb"])
+    np.testing.assert_array_equal(live, [-(-n // page) for n in lens])
+    clean = np.asarray(bt)
+    poisoned = clean.copy()
+    for r in range(len(lens)):
+        poisoned[r, live[r]:] = clean[(r + 1) % len(lens), 0] or clean[0, 0]
+    q = jnp.asarray(rng.normal(size=(len(lens), c["kh"], c["g"], c["hd"])),
+                    jnp.float32)
+    args = (q, kc, ks, vc, vs, pool_pos)
+    qp = jnp.asarray(q_pos)
+    got = np.asarray(paged_decode_attention(*args, jnp.asarray(poisoned), qp))
+    want = np.asarray(ref.paged_decode_attention_ref(
+        *args, jnp.asarray(clean), qp))
+    seen = q_pos >= 0  # the oracle does not model a free row
+    np.testing.assert_allclose(got[seen], want[seen], rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(got[~seen], 0.0)
+    # the poison is live: read past the last live page, it changes rows
+    wrong = np.asarray(ref.paged_decode_attention_ref(
+        *args, jnp.asarray(poisoned), qp))
+    for r in np.flatnonzero(seen & (live < c["maxb"])):
+        assert np.max(np.abs(wrong[r] - want[r])) > 1e-3, (case, r)
+
+
+def test_paged_kernel_all_rows_free_read_nothing():
+    """Every row free (q_pos = -1) over a table still pointing at live
+    pages: the walk covers no entry and every row is exact zeros."""
+    rng = np.random.default_rng(9)
+    kc, ks, vc, vs, pool_pos, bt = _pool_fixture(rng)
+    q = jnp.asarray(rng.normal(size=(3, 2, 2, 32)), jnp.float32)
+    q_pos = np.full((3,), -1, np.int32)
+    assert live_pages(q_pos, 16, bt.shape[1]).sum() == 0
+    out = paged_decode_attention(q, kc, ks, vc, vs, pool_pos, bt,
+                                 jnp.asarray(q_pos))
+    np.testing.assert_array_equal(np.asarray(out), 0.0)
+
+
+def test_pages_per_step_and_live_pages():
+    assert pages_per_step(16, 192) == 16
+    assert pages_per_step(16, 3) == 3
+    assert pages_per_step(64, 192) == 4
+    assert pages_per_step(512, 192) == 1
+    np.testing.assert_array_equal(
+        live_pages(np.asarray([-1, 0, 15, 16, 31, 32, 10_000]), 16, 192),
+        [0, 1, 1, 2, 2, 3, 192])
 
 
 # -------------------------------------------------- model-level parity

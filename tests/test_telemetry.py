@@ -457,6 +457,37 @@ def test_kv_tokens_read_matches_pool_lengths_and_work_rebuild(tiny_model,
         assert rec.kv_tokens_read == want, rec.tick
 
 
+@pytest.mark.parametrize("tick_mode", ["chunked", "packed"])
+def test_kv_pages_walked_is_the_decode_rows_live_pages(tiny_model,
+                                                       tick_mode):
+    """kv_pages_walked counts the block-table entries the paged decode
+    kernel walks: on every chunked tick, sum(q_pos // page + 1) over the
+    rows of its decode call (read off the call's own position operand;
+    free rows, q_pos = -1, walk none). The packed tick's varlen kernel
+    walks no decode table, so it counts none."""
+    cfg, params = tiny_model
+    tracer = Tracer()
+    walked = {}  # tick -> entries the decode call's rows walk
+
+    def observe(sched):
+        real = sched._decode
+
+        def spy(*a, pos, **k):
+            p = np.asarray(pos)
+            walked[sched._tick] = int((p[p >= 0] // 4 + 1).sum())
+            return real(*a, pos=pos, **k)
+
+        sched._decode = spy
+
+    _chunked_run(cfg, params, tracer, tick_mode=tick_mode, wrap=observe)
+    for rec in tracer.ticks:
+        assert rec.kv_pages_walked == walked.get(rec.tick, 0), rec.tick
+    if tick_mode == "chunked":
+        # the walk grows with the rows and shrinks as they leave
+        assert len(set(walked.values())) > 3
+        assert sum(rec.kv_pages_walked > 0 for rec in tracer.ticks) > 5
+
+
 @pytest.mark.parametrize("tick_mode", ["chunked", "packed", "wave"])
 def test_step_calls_count_the_dispatches(tiny_model, tick_mode):
     """TickRecord.step_calls counts every jitted model call a tick
